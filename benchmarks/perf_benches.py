@@ -50,10 +50,12 @@ api_version 5 additions (the scale-out engine):
   ``chunk_ticks`` aligned to divide the budget, so every chunk takes
   the select-free fast body (no masked remainder);
 * ``sharded_sweep`` — a heterogeneous-horizon scenario sweep, sorted by
-  expected horizon, run unsharded vs ``shard=True``. Runs in a CHILD
-  process with ``--devices`` virtual CPU devices forced, so the main
-  process — and every guarded regression metric — stays on an unsplit
-  host: ``scenarios_per_sec_sharded``, device count, and the speedup;
+  expected horizon, run unsharded vs ``shard=True`` in the bench's own
+  process (a chip belongs to the one process that touched JAX first).
+  On CPU, ``--devices`` virtual devices are forced before JAX starts,
+  so every block of the bench runs on the split host. Fewer than two
+  devices is an error, not a skipped block: ``scenarios_per_sec_sharded``,
+  device count, and the speedup;
 * ``calibration`` — a fixed tiny scenario re-measured on every box;
   ``scripts/bench_compare.py`` normalizes cross-box regression ratios
   by it so machine drift stops masquerading as engine regressions.
@@ -123,40 +125,13 @@ import numpy as np
 
 
 def _force_host_devices(n: int) -> None:
-    """Split the host CPU into n virtual devices (the sharded-sweep
-    child process). Only effective before the first jax import (jax
-    locks the backend), and only when the user hasn't already forced a
-    count."""
+    """Split the host CPU into n virtual devices for the sharded sweep.
+    Only effective before the first jax import (jax locks the backend),
+    and only when the user hasn't already forced a count."""
     flags = os.environ.get("XLA_FLAGS", "")
     if n > 1 and "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={n}".strip())
-
-
-def _sharded_sweep_subprocess(devices: int) -> dict:
-    """Run `_sharded_sweep` in a child interpreter with the device split
-    forced there, so the main bench process — and every guarded
-    regression metric measured in it — runs on an unsplit host (the
-    split redistributes XLA's CPU threads and would skew the other
-    numbers)."""
-    import subprocess
-    import sys
-
-    if devices <= 1:
-        return {"devices": max(devices, 1),
-                "skipped": "sharding disabled (--devices <= 1)"}
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(repo, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, "-m", "benchmarks.perf_benches",
-           "--sharded-only", "--devices", str(devices)]
-    r = subprocess.run(cmd, capture_output=True, text=True, cwd=repo,
-                       env=env)
-    if r.returncode != 0:
-        return {"devices": devices,
-                "skipped": f"child process failed: {r.stderr[-500:]}"}
-    return json.loads(r.stdout)
 
 
 def _bench_config(ticks: int):
@@ -280,7 +255,7 @@ def _seed_style_simulate(g, wl, prof, p, mask, seed):
     return fabric._to_result(final, outs, wl.size)
 
 
-def run_benches(b: int, ticks: int, devices: int = 4) -> dict:
+def run_benches(b: int, ticks: int) -> dict:
     import jax
 
     from repro.network.fabric import simulate, simulate_batch
@@ -381,7 +356,7 @@ def run_benches(b: int, ticks: int, devices: int = 4) -> dict:
     results["fabric_health"] = _fabric_health()
     results["corruption_sweep"] = _corruption_sweep()
     results["model_sweep"] = _model_sweep()
-    results["sharded_sweep"] = _sharded_sweep_subprocess(devices)
+    results["sharded_sweep"] = _sharded_sweep()
     results["calibration"] = _calibration()
     return results
 
@@ -403,8 +378,9 @@ def _sharded_sweep(b: int = 32, budget: int = 4096) -> dict:
 
     ndev = len(jax.devices())
     if ndev < 2:
-        return {"devices": ndev,
-                "skipped": "one device visible (pass --devices N on CPU)"}
+        raise RuntimeError(
+            f"the sharded sweep needs >= 2 devices, {ndev} visible (on "
+            f"CPU pass --devices N before JAX starts)")
     g = leaf_spine(leaves=2, spines=4, hosts_per_leaf=8)
     f = 8
     sizes = np.geomspace(60, 1200, b).astype(int)
@@ -1020,22 +996,16 @@ def main() -> None:
     ap.add_argument("--scenarios", type=int, default=8)
     ap.add_argument("--ticks", type=int, default=600)
     ap.add_argument("--devices", type=int, default=4,
-                    help="virtual CPU devices for the sharded sweep "
-                         "(forced in a child process only; 0/1 disables)")
-    ap.add_argument("--sharded-only", action="store_true",
-                    help="internal: run just the sharded sweep with the "
-                         "device split forced, print its json to stdout "
-                         "(the child-process half of the main bench)")
+                    help="virtual CPU devices, forced before JAX starts "
+                         "(the sharded sweep needs >= 2)")
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "..", "BENCH_fabric.json"))
     args = ap.parse_args()
 
-    if args.sharded_only:
-        _force_host_devices(args.devices)
-        print(json.dumps(_sharded_sweep(), indent=2, sort_keys=True))
-        return
-
-    results = run_benches(args.scenarios, args.ticks, args.devices)
+    _force_host_devices(args.devices)
+    from repro.compile_cache import enable_persistent_cache
+    enable_persistent_cache()
+    results = run_benches(args.scenarios, args.ticks)
     results["timestamp"] = time.strftime("%Y-%m-%dT%H:%M:%S")
     out = os.path.abspath(args.out)
     with open(out, "w") as f:
@@ -1050,10 +1020,9 @@ def main() -> None:
     cr = results["corruption_sweep"]
     ms = results["model_sweep"]
     sh = results["sharded_sweep"]
-    sh_line = (f"sharded sweep skipped ({sh['skipped']})" if "skipped" in sh
-               else f"sharded sweep {sh['shard_speedup']:.2f}x on "
-                    f"{sh['devices']} devices "
-                    f"({sh['scenarios_per_sec_sharded']:.1f} scen/s)")
+    sh_line = (f"sharded sweep {sh['shard_speedup']:.2f}x on "
+               f"{sh['devices']} devices "
+               f"({sh['scenarios_per_sec_sharded']:.1f} scen/s)")
     print(f"\nbatched sweep (cold, incl. compile) is "
           f"{results['batch_speedup_vs_serial']:.1f}x the seed-style serial "
           f"sweep; warm-vs-warm against the shared-executable serial loop it "

@@ -1,14 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-import jax
-
-#: single import-time backend probe shared by every kernel module, so the
-#: compiled-vs-interpret dispatch policy lives in exactly one place.
-ON_TPU = jax.default_backend() == "tpu"
-
-
-def auto_interpret(interpret: "bool | None") -> bool:
-    """Resolve a kernel's `interpret` arg: None = auto (compiled on TPU,
-    interpreted elsewhere)."""
-    return (not ON_TPU) if interpret is None else interpret
+"""Pallas kernels for the fabric tick's hot path, their pure-jnp oracles
+(`ref`), and the backend dispatch between the two (`ops`)."""

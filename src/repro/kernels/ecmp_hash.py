@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import auto_interpret
 
 BLOCK_R = 64
 LANES = 128
@@ -51,9 +50,8 @@ def _hash_kernel(src_ref, dst_ref, ev_ref, salt_ref, out_ref, *, fanout: int):
 @functools.partial(jax.jit, static_argnames=("fanout", "interpret"))
 def ecmp_select(src: jax.Array, dst: jax.Array, ev: jax.Array,
                 salt: jax.Array, fanout: int,
-                interpret: bool | None = None) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Port choice for a batch of packets: [N] int32 in [0, fanout)."""
-    interpret = auto_interpret(interpret)
     n = src.shape[0]
     rows = -(-n // LANES)
     pad = rows * LANES - n

@@ -14,8 +14,12 @@ re-expressed as a contraction. For an F-row block,
 
 is an MXU-friendly [R, L] x [L, MP] matmul (counts are small integers,
 exact in f32), and `hits > 0` collapses duplicates back to the OR
-semantics. The bool plane then packs into uint32 ring words on the VPU
-— bits are distinct powers of two per word, so the pack-sum IS the OR.
+semantics. The bool plane then packs into uint32 ring words by two more
+matmuls, [R, MP] x [MP, W], against place-value matrices holding the
+low and the high 16 bits of each word: bits are distinct powers of two
+per word, so each pack-sum IS the OR, and every product and partial sum
+is an integer below 2^16, exact in f32. (Mosaic cannot split the lane
+axis into [W, 32] to pack on the VPU, and reduces no unsigned integers.)
 
 Block layout: (BLOCK_F rows) x (MP bit-lanes, a multiple of 128) per
 grid step; the lane operands (flow / off / valid) ride along whole, one
@@ -30,15 +34,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import auto_interpret
-
 BLOCK_F = 64
 WORD = 32
 
 
 def _nack_kernel(rtx_ref, flow_ref, off_ref, valid_ref, out_ref,
                  *, w: int, lanes: int, num_flows: int):
-    rtx = rtx_ref[...][:, :w]                        # [R, W] uint32
+    rtx = rtx_ref[:, :w]                             # [R, W] uint32
     flow = flow_ref[...][:, 0]                       # [Lp] int32
     off = off_ref[...][:, 0]                         # [Lp] int32
     valid = valid_ref[...][:, 0] != 0                # [Lp]
@@ -57,25 +59,29 @@ def _nack_kernel(rtx_ref, flow_ref, off_ref, valid_ref, out_ref,
     posmat = (jnp.clip(off, 0, mp - 1)[:, None] == m)         # [Lp, MP]
     hits = jnp.dot(rowhot.astype(jnp.float32), posmat.astype(jnp.float32),
                    preferred_element_type=jnp.float32)        # [R, MP]
-    plane = hits > 0.5
+    plane = (hits > 0.5).astype(jnp.float32)
 
-    words = (plane.reshape(R, w, WORD).astype(jnp.uint32)
-             << jax.lax.broadcasted_iota(jnp.uint32, (R, w, WORD), 2)
-             ).sum(axis=2, dtype=jnp.uint32)                  # [R, W]
-    out = out_ref[...]
-    out_ref[...] = out.at[:, :w].set(rtx | words)
+    m = jax.lax.broadcasted_iota(jnp.int32, (mp, w), 0)
+    bit = m % WORD
+    in_word = (m // WORD) == jax.lax.broadcasted_iota(jnp.int32, (mp, w), 1)
+    place = (1 << (bit % 16)).astype(jnp.float32)
+    lo, hi = (jnp.dot(plane, jnp.where(in_word & half, place, 0.0),
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+              for half in (bit < 16, bit >= 16))                # [R, W]
+    words = jax.lax.bitcast_convert_type((hi << 16) | lo, jnp.uint32)
+    out_ref[:, :w] = rtx | words
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def nack_mark(rtx: jax.Array, flow: jax.Array, off: jax.Array,
-              valid: jax.Array, interpret: "bool | None" = None
-              ) -> jax.Array:
+              valid: jax.Array, interpret: bool = False) -> jax.Array:
     """OR lane-requested retransmit bits into [F, W] uint32 rings.
 
     flow/off: [L] int32 (off is a PSN offset in [0, W*32)); valid: [L]
     bool. Invalid, out-of-range-row lanes mark nothing.
+    ``interpret=True`` runs the body in the Pallas interpreter (CPU
+    validation only).
     """
-    interpret = auto_interpret(interpret)
     f, w = rtx.shape
     lanes = flow.shape[0]
     assert w <= 32

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.cms.nscc import NSCCParams
-from repro.kernels import auto_interpret
 
 BLOCK_R = 64
 LANES = 128
@@ -52,7 +51,7 @@ def _nscc_kernel(cwnd_ref, ecn_ref, rtt_ref, count_ref, out_ref, *,
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def nscc_update(cwnd: jax.Array, ecn: jax.Array, rtt: jax.Array,
                 count: jax.Array, params: NSCCParams = NSCCParams(),
-                interpret: bool | None = None) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Update N congestion windows in one fused VPU pass.
 
     Args:
@@ -61,10 +60,9 @@ def nscc_update(cwnd: jax.Array, ecn: jax.Array, rtt: jax.Array,
       rtt:   [N] float32    — measured RTT (ticks or µs, caller's choice;
                               must match params.base_rtt units)
       count: [N] int32      — ACKed packets this round (0 = no update)
-      interpret: run the kernel body in interpret mode (CPU validation);
-        None = auto (compiled on TPU, interpreted elsewhere).
+      interpret: run the kernel body in the Pallas interpreter (CPU
+        validation only).
     """
-    interpret = auto_interpret(interpret)
     n = cwnd.shape[0]
     rows = -(-n // LANES)
     pad = rows * LANES - n

@@ -1,66 +1,51 @@
-"""Public jit'd entry points for the kernel package.
+"""The fabric tick's hot-path ops.
 
-Every op has a Pallas kernel and a pure-jnp oracle (ref.py); dispatch is
-``use_pallas``:
+Each op is a Pallas kernel with a pure-jnp oracle (ref.py). Which of the
+two runs is decided once per trace, when a caller such as the fabric
+tick is traced, from the backend that trace runs on:
 
-* ``None`` (default) — auto: Pallas on TPU (compiled), jnp oracle on CPU,
-  where XLA fuses the reference well and Pallas interpret mode would be
-  the silent slow path.
-* ``True`` — force the Pallas kernel (interpret mode off-TPU, for
-  validation).
-* ``False`` — force the jnp oracle.
+* TPU — the compiled Pallas kernel (``interpret=False``), always. No
+  TPU path runs a kernel in interpret mode or falls back to the oracle.
+* any other backend (the CPU test path) — the jnp oracle, which XLA
+  fuses well there; Pallas interpret mode would be the slow path.
 
-The fabric simulator calls these on its per-tick hot path, so the auto
-default is what makes a TPU deployment run the fused kernels.
+Tests that validate a kernel against its oracle off the chip call the
+kernel module with ``interpret=True`` and `ref` directly; a test that
+compiles the TPU program from a CPU host steers `_compiled_kernels`.
+(``nscc_update.py`` and ``ecmp_hash.py`` hold kernels the tick does not
+call; tests validate them against their oracles.)
 """
 from __future__ import annotations
 
-from repro.core.cms.nscc import NSCCParams
-from repro.kernels import ON_TPU as _ON_TPU, ref
-from repro.kernels.ecmp_hash import ecmp_select as _ecmp_pallas
+import jax
+
+from repro.kernels import ref
 from repro.kernels.nack_mark import nack_mark as _nack_mark_pallas
-from repro.kernels.nscc_update import nscc_update as _nscc_pallas
 from repro.kernels.sack_bitmap import sack_advance as _sack_pallas
 from repro.kernels.sack_fused import sack_fused as _sack_fused_pallas
 
 
-def _use_pallas(use_pallas: bool | None) -> bool:
-    return _ON_TPU if use_pallas is None else use_pallas
+def _compiled_kernels() -> bool:
+    """The dispatch decision, taken at trace time: compiled Pallas on a
+    TPU backend, the jnp oracle everywhere else."""
+    return jax.default_backend() == "tpu"
 
 
-def nscc_update(cwnd, ecn, rtt, count, params: NSCCParams = NSCCParams(),
-                use_pallas: bool | None = None):
-    if _use_pallas(use_pallas):
-        return _nscc_pallas(cwnd, ecn, rtt, count, params,
-                            interpret=not _ON_TPU)
-    return ref.nscc_update_ref(cwnd, ecn, rtt, count, params)
-
-
-def sack_advance(ring, base, use_pallas: bool | None = None):
-    if _use_pallas(use_pallas):
-        return _sack_pallas(ring, base, interpret=not _ON_TPU)
+def sack_advance(ring, base):
+    if _compiled_kernels():
+        return _sack_pallas(ring, base)
     return ref.sack_advance_ref(ring, base)
 
 
-def sack_fused(ring, base, rtx, mask, use_pallas: bool | None = None):
+def sack_fused(ring, base, rtx, mask):
     """Fused record-rx OR + CACK advance + dual ring shift (Sec. 3.2.5)."""
-    if _use_pallas(use_pallas):
-        return _sack_fused_pallas(ring, base, rtx, mask,
-                                  interpret=not _ON_TPU)
+    if _compiled_kernels():
+        return _sack_fused_pallas(ring, base, rtx, mask)
     return ref.sack_fused_ref(ring, base, rtx, mask)
 
 
-def nack_mark(rtx, flow, off, valid, use_pallas: bool | None = None):
+def nack_mark(rtx, flow, off, valid):
     """Duplicate-safe OR of NACK-requested retransmit bits (Sec. 3.2.4)."""
-    if _use_pallas(use_pallas):
-        return _nack_mark_pallas(rtx, flow, off, valid,
-                                 interpret=not _ON_TPU)
+    if _compiled_kernels():
+        return _nack_mark_pallas(rtx, flow, off, valid)
     return ref.nack_mark_ref(rtx, flow, off, valid)
-
-
-def ecmp_select(src, dst, ev, salt, fanout: int,
-                use_pallas: bool | None = None):
-    if _use_pallas(use_pallas):
-        return _ecmp_pallas(src, dst, ev, salt, fanout,
-                            interpret=not _ON_TPU)
-    return ref.ecmp_hash_ref(src, dst, ev, salt, fanout)

@@ -2,7 +2,9 @@
 
 These are the semantic ground truth: every kernel in this package is
 validated against these functions across shape/dtype sweeps in
-tests/test_kernels_*.py (interpret mode on CPU, compiled on TPU).
+tests/test_kernels.py and tests/test_fabric_batch.py (interpret mode on
+CPU); chip_smoke.py checks the tick's compiled kernels against them on
+the chip.
 """
 from __future__ import annotations
 

@@ -8,10 +8,10 @@ this kernel implements blockwise.
 
 TPU adaptation: the per-row variable shift (a gather in the reference)
 is re-expressed as a one-hot masked reduction — for output word j we sum
-ring[:, k] * [k == j + word_shift] over k, an MXU/VPU-friendly W x W
-contraction with W = ring words (W <= 32), instead of a data-dependent
-gather which the TPU vector unit cannot do across lanes. Bit-level ops
-(ctz/popcount) stay in uint32 lanes.
+ring[:, k] * [k == j + word_shift] over k, a W x W contraction with
+W = ring words (W <= 32), instead of a data-dependent gather which the
+TPU vector unit cannot do across lanes. The advance and shift are
+``sack_fused.cack_shift``, shared with the fused source-side kernel.
 
 Block layout: (BLOCK_R rows) x (W words padded to 128 lanes) per grid
 step; every operand tile lives in VMEM.
@@ -24,70 +24,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.pds import _popcount32
-from repro.kernels import auto_interpret
-
-BLOCK_R = 64
-WORD = 32
+from repro.kernels.sack_fused import BLOCK_R, cack_shift, store_advance
 
 
 def _sack_kernel(ring_ref, base_ref, ring_out_ref, base_out_ref, adv_ref,
                  *, w: int):
-    ring = ring_ref[...][:, :w]          # [R, W] uint32
-    base = base_ref[...]                 # [R, 128] uint32 (col 0 used)
-    R = ring.shape[0]
-
-    # --- trailing ones per row ---
-    inv = ~ring
-    lsb = inv & (jnp.uint32(0) - inv)
-    ctz = _popcount32(lsb - jnp.uint32(1))
-    ctz = jnp.where(inv == jnp.uint32(0), WORD, ctz)          # all-ones word
-    full = ring == jnp.uint32(0xFFFFFFFF)                      # [R, W]
-    # number of leading full words = index of first non-full word
-    not_full = ~full
-    col = jax.lax.broadcasted_iota(jnp.int32, (R, w), 1)
-    first_partial = jnp.min(jnp.where(not_full, col, w), axis=1)  # [R]
-    # bits from the first partial word (0 if none)
-    sel = col == first_partial[:, None]
-    partial_bits = jnp.sum(jnp.where(sel, ctz, 0), axis=1)
-    adv = jnp.where(first_partial == w, w * WORD,
-                    first_partial * WORD + partial_bits)       # [R]
-
-    # --- funnel shift right by adv bits, expressed gather-free ---
-    words = adv // WORD                                        # [R]
-    bits = (adv % WORD).astype(jnp.uint32)                     # [R]
-    # lo[i, j] = ring[i, j + words[i]] ; hi[i, j] = ring[i, j + words[i] + 1]
-    shift_idx = col + words[:, None]                           # [R, W]
-    k = jax.lax.broadcasted_iota(jnp.int32, (R, w, w), 2)      # [R, W, W]
-    one_hot_lo = (k == shift_idx[:, :, None]).astype(jnp.uint32)
-    one_hot_hi = (k == (shift_idx + 1)[:, :, None]).astype(jnp.uint32)
-    ring_b = ring[:, None, :]                                  # [R, 1, W]
-    lo = jnp.sum(ring_b * one_hot_lo, axis=2, dtype=jnp.uint32)
-    hi = jnp.sum(ring_b * one_hot_hi, axis=2, dtype=jnp.uint32)
-    b = bits[:, None]
-    shifted = jnp.where(b == 0, lo,
-                        (lo >> b) | (hi << (jnp.uint32(WORD) - b)))
-
-    out = ring_out_ref[...]
-    out = out.at[:, :w].set(shifted)
-    ring_out_ref[...] = out
-    base_out_ref[...] = base + adv.astype(jnp.uint32)[:, None] * (
-        jax.lax.broadcasted_iota(jnp.int32, base.shape, 1) == 0
-    ).astype(jnp.uint32)
-    adv_ref[...] = adv[:, None] * (
-        jax.lax.broadcasted_iota(jnp.int32, base.shape, 1) == 0)
+    adv, (shifted,) = cack_shift(ring_ref[:, :w])
+    ring_out_ref[:, :w] = shifted
+    store_advance(base_ref, base_out_ref, adv_ref, adv)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sack_advance(ring: jax.Array, base: jax.Array,
-                 interpret: bool | None = None):
+def sack_advance(ring: jax.Array, base: jax.Array, interpret: bool = False):
     """CACK-advance every PDC's SACK ring.
 
     ring: [N, W] uint32 (W <= 32 words = up to 1024-PSN MP_RANGE window)
     base: [N] uint32
-    Returns (new_ring, new_base, advanced[int32]).
+    Returns (new_ring, new_base, advanced[int32]). ``interpret=True``
+    runs the body in the Pallas interpreter (CPU validation only).
     """
-    interpret = auto_interpret(interpret)
     n, w = ring.shape
     assert w <= 128
     rows = -(-n // BLOCK_R) * BLOCK_R

@@ -157,7 +157,6 @@ def sharded_cache_attention(mesh, dp_axes):
     output psum is [B,H,1,hd] — a few hundred KB per layer instead of
     gigabytes. (§Perf decode iteration 3.)
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
 
@@ -179,13 +178,13 @@ def sharded_cache_attention(mesh, dp_axes):
         o = jax.lax.psum(o, "model")                          # [B,H,1,hd]
         return (o / jnp.maximum(l[..., None], 1e-30))
 
-    return shard_map(
+    return jax.shard_map(
         local_attn, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, None, "model", None),
                   P(dp, None, "model", None), P("model"), P("model"),
                   P(), P(None)),
         out_specs=P(dp, None, None, None),
-        check_rep=False)
+        check_vma=False)
 
 
 def attention_fwd(params, x, positions, *, n_q, n_kv, head_dim,

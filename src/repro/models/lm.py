@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import (ArchConfig, FFN_MLP, FFN_MOE, FFN_RWKV,
                                 MIXER_ATTN, MIXER_MAMBA, MIXER_RWKV)
@@ -241,11 +240,11 @@ def _moe_block(cfg: ArchConfig, mesh, dp_axes, token_spec,
 
     wspec_in = P(None, None, "model")    # [E, D, F/tp]
     wspec_out = P(None, "model", None)   # [E, F/tp, D]
-    return shard_map(
+    return jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(token_spec, P(None, None), wspec_in, wspec_in, wspec_out),
         out_specs=(token_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -32,7 +32,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -95,10 +94,10 @@ def _sharded_fns(g, profile, p, F: int, trace: str, devs: tuple,
             #   -> (state, stopped, time-major out lanes [T, B, ...])
             in_specs = (sc, sc, rep, sc, sc, rep)
             out_specs = (sc, sc, P(None, _AXIS))
-        run_sh = shard_map(run, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-        init_sh = shard_map(init_fn, mesh=mesh, in_specs=(sc, sc),
-                            out_specs=sc, check_rep=False)
+        run_sh = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
+        init_sh = jax.shard_map(init_fn, mesh=mesh, in_specs=(sc, sc),
+                                out_specs=sc, check_vma=False)
         fns = (jax.jit(init_sh), jax.jit(run_sh, donate_argnums=(0,)))
         fabric._RUN_CACHE[key] = fns
     return fns

@@ -1,11 +1,16 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(interpret mode on CPU)."""
+(interpret mode on CPU; `chip_smoke.py` runs the compiled kernels of the
+fabric tick against the same oracles on the chip)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.cms.nscc import NSCCParams
-from repro.kernels import ops
+from repro.kernels import ops, ref
+from repro.kernels.ecmp_hash import ecmp_select
+from repro.kernels.nack_mark import nack_mark
+from repro.kernels.nscc_update import nscc_update
+from repro.kernels.sack_bitmap import sack_advance
 
 RNG = np.random.default_rng(7)
 
@@ -16,8 +21,8 @@ def test_nscc_update_matches_ref(n):
     ecn = jnp.asarray(RNG.integers(0, 2, n), jnp.int32)
     rtt = jnp.asarray(RNG.uniform(0.5, 60, n), jnp.float32)
     cnt = jnp.asarray(RNG.integers(0, 5, n), jnp.int32)
-    a = ops.nscc_update(cwnd, ecn, rtt, cnt, use_pallas=True)
-    b = ops.nscc_update(cwnd, ecn, rtt, cnt, use_pallas=False)
+    a = nscc_update(cwnd, ecn, rtt, cnt, interpret=True)
+    b = ref.nscc_update_ref(cwnd, ecn, rtt, cnt, NSCCParams())
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 
@@ -32,8 +37,8 @@ def test_nscc_update_param_sweep(params):
     ecn = jnp.asarray(RNG.integers(0, 2, n), jnp.int32)
     rtt = jnp.asarray(RNG.uniform(0.5, 80, n), jnp.float32)
     cnt = jnp.asarray(RNG.integers(0, 3, n), jnp.int32)
-    a = ops.nscc_update(cwnd, ecn, rtt, cnt, params, use_pallas=True)
-    b = ops.nscc_update(cwnd, ecn, rtt, cnt, params, use_pallas=False)
+    a = nscc_update(cwnd, ecn, rtt, cnt, params, interpret=True)
+    b = ref.nscc_update_ref(cwnd, ecn, rtt, cnt, params)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
     assert (np.asarray(a) >= params.min_cwnd - 1e-6).all()
     assert (np.asarray(a) <= params.max_cwnd + 1e-6).all()
@@ -45,8 +50,8 @@ def test_sack_advance_matches_ref(n, w):
     ring = jnp.asarray(
         RNG.integers(0, 2 ** 32, (n, w), dtype=np.uint32))
     base = jnp.asarray(RNG.integers(0, 10000, n, dtype=np.uint32))
-    r1, b1, a1 = ops.sack_advance(ring, base, use_pallas=True)
-    r2, b2, a2 = ops.sack_advance(ring, base, use_pallas=False)
+    r1, b1, a1 = sack_advance(ring, base, interpret=True)
+    r2, b2, a2 = ref.sack_advance_ref(ring, base)
     np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
     np.testing.assert_array_equal(np.asarray(b1), np.asarray(b2))
     np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
@@ -58,7 +63,7 @@ def test_sack_advance_edge_cases():
                       jnp.zeros((8,), jnp.uint32),
                       jnp.asarray([1, 0, 0, 0, 0, 0, 0, 0], jnp.uint32)])
     base = jnp.zeros((3,), jnp.uint32)
-    r, b, a = ops.sack_advance(ring, base, use_pallas=True)
+    r, b, a = sack_advance(ring, base, interpret=True)
     np.testing.assert_array_equal(np.asarray(a), [256, 0, 1])
     np.testing.assert_array_equal(np.asarray(b), [256, 0, 1])
     assert int(np.asarray(r)[0].sum()) == 0
@@ -71,8 +76,8 @@ def test_ecmp_select_matches_ref(n, fanout):
     dst = jnp.asarray(RNG.integers(0, 1 << 20, n), jnp.int32)
     ev = jnp.asarray(RNG.integers(0, 65536, n), jnp.int32)
     salt = jnp.asarray(RNG.integers(0, 256, n), jnp.int32)
-    a = ops.ecmp_select(src, dst, ev, salt, fanout, use_pallas=True)
-    b = ops.ecmp_select(src, dst, ev, salt, fanout, use_pallas=False)
+    a = ecmp_select(src, dst, ev, salt, fanout, interpret=True)
+    b = ref.ecmp_hash_ref(src, dst, ev, salt, fanout)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert (np.asarray(a) >= 0).all() and (np.asarray(a) < fanout).all()
 
@@ -84,8 +89,8 @@ def test_ecmp_determinism_and_spread():
     src = jnp.zeros((n,), jnp.int32)
     dst = jnp.ones((n,), jnp.int32)
     salt = jnp.full((n,), 3, jnp.int32)
-    p1 = ops.ecmp_select(src, dst, ev, salt, 4, use_pallas=True)
-    p2 = ops.ecmp_select(src, dst, ev, salt, 4, use_pallas=True)
+    p1 = ecmp_select(src, dst, ev, salt, 4, interpret=True)
+    p2 = ecmp_select(src, dst, ev, salt, 4, interpret=True)
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
     hist = np.bincount(np.asarray(p1), minlength=4) / n
     np.testing.assert_allclose(hist, 0.25, atol=0.02)
@@ -100,8 +105,8 @@ def test_nack_mark_matches_ref(f, w, lanes):
     # the fabric always hands the kernel in-range rows/offsets; clip the
     # sweep the same way so both paths see the contract inputs
     valid = valid & (flow >= 0) & (flow < f) & (off >= 0) & (off < w * 32)
-    a = ops.nack_mark(rtx, flow, off, valid, use_pallas=True)
-    b = ops.nack_mark(rtx, flow, off, valid, use_pallas=False)
+    a = nack_mark(rtx, flow, off, valid, interpret=True)
+    b = ref.nack_mark_ref(rtx, flow, off, valid)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -112,8 +117,9 @@ def test_nack_mark_or_semantics_with_duplicates():
     flow = jnp.asarray([1, 1, 1, 2, 0], jnp.int32)
     off = jnp.asarray([5, 5, 37, 0, 63], jnp.int32)
     valid = jnp.asarray([True, True, True, True, False])
-    for up in (True, False):
-        out = np.asarray(ops.nack_mark(rtx, flow, off, valid, use_pallas=up))
+    for mark in (ops.nack_mark, ref.nack_mark_ref,
+                 lambda *a: nack_mark(*a, interpret=True)):
+        out = np.asarray(mark(rtx, flow, off, valid))
         assert out[1, 0] == 1 << 5
         assert out[1, 1] == 1 << (37 - 32)
         assert out[2, 0] == 1
@@ -122,7 +128,7 @@ def test_nack_mark_or_semantics_with_duplicates():
 
 def test_nack_mark_preserves_existing_bits():
     rtx = jnp.full((2, 2), 0x80000001, jnp.uint32)
-    out = np.asarray(ops.nack_mark(
+    out = np.asarray(nack_mark(
         rtx, jnp.asarray([0], jnp.int32), jnp.asarray([1], jnp.int32),
-        jnp.asarray([True]), use_pallas=True))
+        jnp.asarray([True]), interpret=True))
     assert out[0, 0] == 0x80000003 and out[1, 0] == 0x80000001
