@@ -260,6 +260,13 @@ def run_benches(b: int, ticks: int) -> dict:
 
     from repro.network.fabric import simulate, simulate_batch
 
+    # the sharded sweep runs last: fail before any block rather than
+    # throw away every measurement at the end
+    ndev = len(jax.devices())
+    if ndev < 2:
+        raise RuntimeError(
+            f"the sharded sweep needs >= 2 devices, {ndev} visible (on "
+            f"CPU pass --devices N before JAX starts)")
     g, wl, prof, p = _bench_config(ticks)
     wls, masks, seeds = _scenarios(g, wl, b)
     fq = [tuple(np.nonzero(masks[i])[0].tolist()) for i in range(b)]
@@ -377,10 +384,6 @@ def _sharded_sweep(b: int = 32, budget: int = 4096) -> dict:
     from repro.network.topology import leaf_spine
 
     ndev = len(jax.devices())
-    if ndev < 2:
-        raise RuntimeError(
-            f"the sharded sweep needs >= 2 devices, {ndev} visible (on "
-            f"CPU pass --devices N before JAX starts)")
     g = leaf_spine(leaves=2, spines=4, hosts_per_leaf=8)
     f = 8
     sizes = np.geomspace(60, 1200, b).astype(int)

@@ -44,7 +44,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.compile_cache import enable_persistent_cache  # noqa: E402
 from repro.core.link import state_bitwise_equal  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.network import fabric, shard  # noqa: E402
+from repro.network import fabric  # noqa: E402
 from repro.network.collectives import (CollectiveSpec,  # noqa: E402
                                        build_workload, expected_host_rx)
 from repro.network.faults import FaultSchedule  # noqa: E402
@@ -237,16 +237,15 @@ class Batch:
         return fabric.simulate_batch(self.g, wls, self.profile, self.params,
                                      faults=fault, seeds=self.seeds, **kw)
 
-    def compiled(self, get_fns, **kw):
-        """The executable ``simulate_batch`` ran for this batch: the same
-        cached jitted driver (``get_fns(g, profile, p, F, ..., **kw)``)
-        lowered on the same arguments, which JAX serves from its
-        in-memory cache."""
+    def compiled(self, devs=None):
+        """The executable ``simulate_batch`` ran for this batch (sharded
+        over `devs` if given): the cached jitted driver that
+        ``fabric.driver_fns`` hands ``simulate_batch``, lowered on the
+        same arguments, which JAX serves from its in-memory cache."""
         wls, fault = self.stacked()
-        init, run = get_fns(
-            self.g, self.profile, self.params, int(wls.src.shape[1]),
-            trace="stats", lossy=bool(np.asarray(fault.loss_p).any()),
-            hosty=fault.has_host_faults, corrupty=fault.has_corruption, **kw)
+        init, run = fabric.driver_fns(
+            self.g, self.profile, self.params, int(wls.src.shape[1]), fault,
+            "stats", batched=True, devs=devs)
         budget = self.params.ticks
         return run.lower(init(wls, jnp.asarray(self.seeds)), wls, fault,
                          jnp.int32(budget), jnp.int32(0),
@@ -287,8 +286,7 @@ def main_path_phase(b: Batch, listed: int = LISTED,
     t0 = time.perf_counter()
     warm = b.run()
     warm_s = time.perf_counter() - t0
-    calls = b.compiled(fabric._get_fns, batched=True).as_text().count(
-        TPU_CUSTOM_CALL)
+    calls = b.compiled().as_text().count(TPU_CUSTOM_CALL)
     horizons = np.asarray([r.horizon for r in warm])
     _say(f"(b) smoke reading, not a benchmark: {b.size} scenarios of "
          f"{len(b.workload().src)} flows on {b.g.name}: compile "
@@ -365,7 +363,7 @@ def sharded_phase(b: Batch, devices: int) -> dict:
     _check(not diffs, f"sharded lanes differ: {diffs}")
 
     # where the sharded executable put each device's scenario lanes
-    horizon = b.compiled(shard._sharded_fns, devs=devs).output_shardings[2]
+    horizon = b.compiled(devs).output_shardings[2]
     placed = {d.id: (idx[0].start, idx[0].stop) for d, idx
               in horizon.devices_indices_map((b.size,)).items()}
     per = b.size // devices

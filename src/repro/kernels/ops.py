@@ -2,16 +2,19 @@
 
 Each op is a Pallas kernel with a pure-jnp oracle (ref.py). Which of the
 two runs is decided once per trace, when a caller such as the fabric
-tick is traced, from the backend that trace runs on:
+tick is traced, from the process's default backend
+(``jax.default_backend()``), not from the device a jit targets:
 
 * TPU — the compiled Pallas kernel (``interpret=False``), always. No
   TPU path runs a kernel in interpret mode or falls back to the oracle.
 * any other backend (the CPU test path) — the jnp oracle, which XLA
   fuses well there; Pallas interpret mode would be the slow path.
 
-Tests that validate a kernel against its oracle off the chip call the
-kernel module with ``interpret=True`` and `ref` directly; a test that
-compiles the TPU program from a CPU host steers `_compiled_kernels`.
+So on a TPU host a tick jitted for a CPU device still gets the Pallas
+kernels, and a CPU host compiling for a described TPU gets the oracles
+unless a test steers `_compiled_kernels`. Tests that validate a kernel
+against its oracle off the chip call the kernel module with
+``interpret=True`` and `ref` directly.
 (``nscc_update.py`` and ``ecmp_hash.py`` hold kernels the tick does not
 call; tests validate them against their oracles.)
 """

@@ -1794,6 +1794,24 @@ def _get_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
     return fns
 
 
+def driver_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
+               F: int, fault: FaultSchedule, trace: str, batched: bool,
+               tel: "TelemetrySpec | None" = None,
+               link: "LinkConfig | None" = None, devs=None):
+    """The cached (init, run) pair that ``simulate`` (``batched=False``)
+    or ``simulate_batch`` runs for `fault`; `devs` (a device tuple)
+    selects the sharded executable. The one place the schedule-derived
+    statics (``lossy``, ``hosty``, ``corrupty``) are read off a schedule."""
+    statics = dict(lossy=bool(np.asarray(fault.loss_p).any()),
+                   hosty=fault.has_host_faults,
+                   corrupty=fault.has_corruption, tel=tel, link=link)
+    if devs is None:
+        return _get_fns(g, profile, p, F, batched=batched, trace=trace,
+                        **statics)
+    from repro.network import shard
+    return shard._sharded_fns(g, profile, p, F, trace, devs, **statics)
+
+
 def _run_full_host(run_chunk, s0, wl, fault, budget: int, chunk: int,
                    batch: "int | None"):
     """Drive the trace="full" chunk executable from the host: run chunks
@@ -2012,12 +2030,8 @@ def simulate(g: QueueGraph, wl: Workload,
                         g_num_hosts=g.num_hosts)
     if fault is None:
         fault = FaultSchedule.from_mask(_failed_to_mask(g, failed))
-    lossy = bool(np.asarray(fault.loss_p).any())
-    hosty = fault.has_host_faults
-    corrupty = fault.has_corruption
-    init, run = _get_fns(g, profile, p, F, batched=False, trace=trace,
-                         lossy=lossy, tel=tel, hosty=hosty,
-                         corrupty=corrupty, link=link)
+    init, run = driver_fns(g, profile, p, F, fault, trace, batched=False,
+                           tel=tel, link=link)
     s0 = init(wl, jnp.uint32(seed))
     if trace == "stats":
         w0, w1 = _window_bounds(goodput_window, budget)
@@ -2070,12 +2084,8 @@ def _run_batch(g, wls, profile, p, fault, seeds, trace, budget,
                                  link=link)
     B, F = wls.src.shape
     profile.delivery_modes(F)
-    lossy = bool(np.asarray(fault.loss_p).any())
-    hosty = fault.has_host_faults
-    corrupty = fault.has_corruption
-    init, run = _get_fns(g, profile, p, F, batched=True, trace=trace,
-                         lossy=lossy, tel=tel, hosty=hosty,
-                         corrupty=corrupty, link=link)
+    init, run = driver_fns(g, profile, p, F, fault, trace, batched=True,
+                           tel=tel, link=link)
     s0 = init(wls, seeds)
     sizes = np.asarray(wls.size)
     if trace == "stats":

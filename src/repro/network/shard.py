@@ -117,9 +117,9 @@ def run_sharded(g, wls, profile, p, fault, seeds, trace: str, budget: int,
     n = len(devs)
     B, F = wls.src.shape
     profile.delivery_modes(F)
-    lossy = bool(np.asarray(fault.loss_p).any())
-    hosty = fault.has_host_faults
-    corrupty = fault.has_corruption
+    init, run = fabric.driver_fns(g, profile, p, F, fault, trace,
+                                  batched=True, tel=tel, link=link,
+                                  devs=devs)
     wls_p, pad = pad_scenarios(wls, n)
     if pad:
         # padding lanes get all-healthy schedules at the batch's own
@@ -130,8 +130,6 @@ def run_sharded(g, wls, profile, p, fault, seeds, trace: str, budget: int,
                                          num_hosts=fault.num_hosts))
         seeds = jnp.concatenate(
             [seeds, jnp.full((pad,), fabric.DEFAULT_SEED, jnp.uint32)])
-    init, run = _sharded_fns(g, profile, p, F, trace, devs, lossy, tel=tel,
-                             hosty=hosty, corrupty=corrupty, link=link)
     s0 = init(wls_p, seeds)
     sizes = np.asarray(wls.size)
     if trace == "stats":

@@ -2,8 +2,8 @@
 
 * the fused record/advance/shift kernel (interpret mode) and its jnp
   oracle agree with the pds reference on edge cases (empty ring, full
-  ring, base wrap-around); `chip_smoke.py` checks the compiled kernel
-  against the oracle on the chip;
+  ring, base wrap-around), and interpret mode agrees with the compiled
+  kernel (compiled only on TPU);
 * `simulate_batch` lanes are bitwise identical to serial `simulate`
   calls across mixed workloads, seeds, and failure masks.
 """
@@ -103,6 +103,21 @@ def test_sack_fused_random_parity(n, w):
     base = jnp.asarray(RNG.integers(0, 2 ** 32, n, dtype=np.uint32))
     _assert_fused_matches(ring, base, rtx, mask, use_pallas=True)
     _assert_fused_matches(ring, base, rtx, mask, use_pallas=False)
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="compiled Pallas path needs a TPU; interpret "
+                           "mode is exercised everywhere else")
+def test_sack_fused_interpret_vs_compiled():
+    n, w = 96, 16
+    ring = jnp.asarray(RNG.integers(0, 2 ** 32, (n, w), dtype=np.uint32))
+    rtx = jnp.asarray(RNG.integers(0, 2 ** 32, (n, w), dtype=np.uint32))
+    mask = jnp.asarray(RNG.integers(0, 2 ** 32, (n, w), dtype=np.uint32))
+    base = jnp.asarray(RNG.integers(0, 2 ** 32, n, dtype=np.uint32))
+    a = sack_fused(ring, base, rtx, mask, interpret=True)
+    b = sack_fused(ring, base, rtx, mask, interpret=False)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ------------------------------------------------------------------------
