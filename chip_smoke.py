@@ -116,9 +116,14 @@ def _leaves_equal(a, b) -> bool:
 
 def _first_difference(a: fabric.SimResult, b: fabric.SimResult
                       ) -> "str | None":
-    """Name of the first SimResult field (or state lane) that differs."""
+    """Name of the first SimResult field (or state lane) that differs.
+    ``driver_chunks`` is left out: it counts the executable's chunks,
+    which a batch and a serial call run differently, not the lane's
+    outcome."""
     for f in dataclasses.fields(a):
         x, y = getattr(a, f.name), getattr(b, f.name)
+        if f.name == "driver_chunks":
+            continue
         if f.name == "state":
             lane = state_bitwise_equal(x, y, skip=())
             if lane is not None:

@@ -80,6 +80,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, replace
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -390,6 +391,36 @@ def _rank_within(target: jax.Array, valid: jax.Array,
     return pos, rank
 
 
+class _Spans:
+    """Consecutive named spans without a nested block each: ``span(name)``
+    closes the open span and opens `name`; ``close()`` or the end of the
+    ``with`` block closes the last. `kind` makes one span:
+    ``jax.named_scope`` (the ``op_name`` metadata of the ops traced
+    inside it; the compiled arithmetic is unchanged) or
+    ``jax.profiler.TraceAnnotation`` (a host span on the profiler's
+    clock). DESIGN.md lists the names."""
+
+    def __init__(self, kind=jax.named_scope):
+        self._kind = kind
+        self._open = None
+
+    def __call__(self, name: str) -> None:
+        self.close()
+        self._open = self._kind(name)
+        self._open.__enter__()
+
+    def close(self) -> None:
+        if self._open is not None:
+            opened, self._open = self._open, None
+            opened.__exit__(None, None, None)
+
+    def __enter__(self) -> "_Spans":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
 def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
               lossy: bool = False, tel: "TelemetrySpec | None" = None,
               hosty: bool = False, corrupty: bool = False,
@@ -499,6 +530,12 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
 
     def step(s: SimState, tick: jax.Array, wl: Workload,
              fault: FaultSchedule):
+        with _Spans() as phase:
+            return phases(s, tick, wl, fault, phase)
+
+    def phases(s: SimState, tick: jax.Array, wl: Workload,
+               fault: FaultSchedule, phase: _Spans):
+        phase("tick.faults")
         flow_src = wl.src
         flow_dst = wl.dst
         slot = tick % D
@@ -523,6 +560,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
             inj_frozen = src_dead | nic[flow_src]
 
         # ------------------------------------------------ 1. control events
+        phase("tick.control")
         evs = s.ev_buf[slot]                                  # [E, 6]
         et = evs[:, EVF_TYPE]
         ef = evs[:, EVF_FLOW]
@@ -664,6 +702,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         ev_buf = s.ev_buf.at[slot, :, EVF_TYPE].set(jnp.int32(EV_NONE))
 
         # ------------------------------------------- 2. RCCC receiver grants
+        phase("tick.grants")
         done = src_track.base.astype(jnp.int32) >= wl.size
         # dependency lane: flow f is eligible only once flow dep[f] has
         # completed at ITS source (CACK == size) — gated in-scan like
@@ -678,6 +717,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         cc_st = cc_pol.on_grant_tick(cc_st, flow_dst, active, H)
 
         # --------------------------------------------------- 3. injection
+        phase("tick.injection")
         has_rtx = (rtx != 0).any(axis=1)
         if all_rod:
             has_rtx = jnp.zeros((F,), jnp.bool_)
@@ -781,6 +821,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
                                  inflight, cc_st)
 
         # ------------------------------------------------- 4. forwarding
+        phase("tick.forwarding")
         qidx = jnp.arange(Q)
         nonempty = s.q_len > 0
         # link-layer transmission gate: `txq` is the set of queues whose
@@ -859,6 +900,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         forward = txq & (nq >= 0)
 
         # --------------------------------------------- 5. delivery at FEPs
+        phase("tick.delivery")
         dtrim = deliver & ((pm & META_TRIMMED) != 0)
         ddata = deliver & ~dtrim
         # one host downlink per destination => at most one delivery per
@@ -906,6 +948,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         cc_st = cc_pol.on_rx_seen(cc_st, hot_seen.any(axis=1))
 
         # ------------------------------------- 6. OOO-count loss inference
+        phase("tick.ooo")
         ooo_fire = jnp.zeros((F,), jnp.bool_)
         if p.ooo_threshold > 0:
             dist = pds.ooo_distance(dst_track)
@@ -915,6 +958,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         last_ooo_nack = jnp.where(ooo_fire, tick, s.last_ooo_nack)
 
         # ---------------------------------- 6b. in-network reduction (INC)
+        phase("tick.inc")
         # Forwarded packets about to enter their destination host downlink
         # and belonging to a reduction group are offered to the ToR's
         # accumulator context: all but the bitmap-completing child are
@@ -939,6 +983,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         inc_emits = s.inc_emits + inc_emit.sum(dtype=jnp.int32)
 
         # ------------------------------------------------- 7. enqueue phase
+        phase("tick.enqueue")
         # candidates: forwarded packets (Q lanes, minus INC absorptions) +
         # injections (F lanes)
         cand_q = jnp.concatenate([jnp.where(forward & ~inc_absorb, nq, -1),
@@ -959,11 +1004,13 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         # is only compiled in when the dispatching schedule has nonzero
         # loss_p (`lossy` static): loss-free runs pay nothing for it.
         if lossy:
-            u = _mix32(_mix32(tick.astype(jnp.uint32)
-                              ^ fault.seed * jnp.uint32(0x9E3779B1))
-                       ^ lane_ids * jnp.uint32(0x85EBCA77))
-            is_lost = cvalid & (u < loss_threshold(fault.loss_p)[safe_cq])
-            cvalid = cvalid & ~is_lost
+            with jax.named_scope("tick.enqueue.loss"):
+                u = _mix32(_mix32(tick.astype(jnp.uint32)
+                                  ^ fault.seed * jnp.uint32(0x9E3779B1))
+                           ^ lane_ids * jnp.uint32(0x85EBCA77))
+                is_lost = cvalid & (
+                    u < loss_threshold(fault.loss_p)[safe_cq])
+                cvalid = cvalid & ~is_lost
         else:
             is_lost = jnp.zeros_like(cvalid)
         if cbfc:
@@ -1062,6 +1109,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
             drops = drops + dst_gone.sum(dtype=jnp.int32)
 
         # ------------------------------------------- 8. schedule control TC
+        phase("tick.control_tc")
         out_slot = (tick + p.ack_return_ticks) % D
         # lanes [0, Q): ACKs from deliveries and from INC absorptions
         # (the switch ACKs an absorbed child exactly like a delivery
@@ -1098,6 +1146,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
             axis=-1))
 
         # ------------------------------------------------- 9. timeouts + QA
+        phase("tick.timeouts")
         timeout_fire = timeout_rod  # ROD rewinds already counted as expiries
         if not all_rod:
             # A flow needs the RTO not only while packets are (believed)
@@ -1135,6 +1184,7 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         cc_st = cc_pol.end_of_tick(cc_st, tick)
 
         # ---------------------------------------- 10. recovery loop lanes
+        phase("tick.recovery")
         # (both arms statically gated: default profiles compile the exact
         # pre-fault-engine tick)
         if backoff_on:
@@ -1223,6 +1273,8 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
             cbfc_consumed=cbfc_consumed, cbfc_freed=cbfc_freed,
             cbfc_ret=cbfc_ret, credit_stall_ticks=credit_stall_ticks,
         )
+        # the out lanes (the full tier's per-tick record) and the probe
+        phase("tick.telemetry")
         out = {
             "delivered": fresh_f.astype(jnp.int32),
             "cwnd": cc_pol.cwnd_view(cc_st, F),
@@ -1295,6 +1347,14 @@ class SimResult:
     when the run was dispatched with ``telemetry=TelemetrySpec.on(...)``
     (``trace="stats"`` only), else ``None``.
 
+    ``driver_chunks`` is ``(fast, masked)``: the chunks the driver's
+    while loop ran through its select-free fast body and through its
+    masked body (frozen lanes or the budget's last chunk). The counts
+    are the executable's (a sharded run's: this lane's device's), the
+    same for every lane it ran; its executed ticks are
+    ``(fast + masked) * chunk_ticks``. ``trace="full"`` drives the
+    chunks from the host and leaves the field ``None``.
+
     Scalar stat counters (streamed in both trace tiers; each also a
     property here):
 
@@ -1341,6 +1401,8 @@ class SimResult:
     stat_abandon_tick: "int | None" = None
     #: reconstructed probe-lane time series (telemetry=TelemetrySpec.on())
     telemetry: "telem.FabricTrace | None" = None
+    #: (fast, masked) chunks the driver ran (trace="stats"; else None)
+    driver_chunks: "tuple[int, int] | None" = None
 
     def completion_ticks(self) -> np.ndarray:
         """Per-flow first tick by which the full message was delivered
@@ -1624,7 +1686,8 @@ def _build_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
     device program: a ``lax.while_loop`` whose body scans a
     ``chunk_ticks``-long chunk (streaming the stat lanes in the scan
     carry) and whose predicate stops once every lane is quiescent or at
-    the (traced) budget.
+    the (traced) budget. The loop counts the chunks it ran through each
+    branch below (stat leaves ``fast_chunks`` and ``masked_chunks``).
 
     ``trace="full"`` builds ONE CHUNK (scan + per-tick out lanes +
     quiescence flag, time-major: ``[chunk, B?, ...]``); the host drives
@@ -1710,11 +1773,13 @@ def _build_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
                     s, st = c
                     tick = tick0 + i
                     ns, out = stepf(s, tick, wl, fault)
-                    nst = statf(st, s, ns, wl, tick, w0, w1, out)
+                    with jax.named_scope("driver.stats"):
+                        nst = statf(st, s, ns, wl, tick, w0, w1, out)
                     if stop is None:
                         return (ns, nst), None
-                    live = (tick < budget) & ~stop
-                    return _freeze(live, (ns, nst), (s, st)), None
+                    with jax.named_scope("driver.freeze"):
+                        live = (tick < budget) & ~stop
+                        return _freeze(live, (ns, nst), (s, st)), None
 
                 (s, st), _ = jax.lax.scan(tick_body, (s, st), xs)
                 return s, st
@@ -1728,25 +1793,33 @@ def _build_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
                 return chunk_scan(s, st, tick0, stop)
 
             def body(c):
-                s, st, tick0, stop, hz = c
+                s, st, tick0, stop, hz, n_fast, n_masked = c
                 fast = (tick0 + chunk <= budget) & ~stop.any()
                 s, st = jax.lax.cond(fast, fast_chunk, masked_chunk,
                                      (s, st, tick0, stop))
+                with jax.named_scope("driver.stats"):
+                    # the chunks this loop ran through each branch
+                    n_fast = n_fast + fast.astype(jnp.int32)
+                    n_masked = n_masked + (~fast).astype(jnp.int32)
                 tick0 = tick0 + jnp.int32(chunk)
-                nstop = stop | quiet(s, wl) | (tick0 >= budget)
-                hz = jnp.where(nstop & ~stop,
-                               jnp.minimum(tick0, budget), hz)
-                return s, st, tick0, nstop, hz
+                with jax.named_scope("driver.quiescent"):
+                    nstop = stop | quiet(s, wl) | (tick0 >= budget)
+                    hz = jnp.where(nstop & ~stop,
+                                   jnp.minimum(tick0, budget), hz)
+                return s, st, tick0, nstop, hz, n_fast, n_masked
 
             stop0 = jnp.broadcast_to(budget <= jnp.int32(0), bshape)
             hz0 = jnp.where(stop0, jnp.minimum(jnp.int32(0), budget), -1)
             st0 = jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(a, bshape + a.shape),
                 stats_init())
-            s, st, _, _, hz = jax.lax.while_loop(
+            zero = jnp.zeros(bshape, jnp.int32)
+            s, st, _, _, hz, n_fast, n_masked = jax.lax.while_loop(
                 lambda c: ~c[3].all(), body,
-                (s0, st0, jnp.int32(0), stop0, hz0))
-            return s, st, hz
+                (s0, st0, jnp.int32(0), stop0, hz0, zero, zero))
+            # per-lane [B] leaves, so a sharded run keeps its specs and
+            # each lane reports its own executable's (or shard's) loop
+            return s, dict(st, fast_chunks=n_fast, masked_chunks=n_masked), hz
 
         return init_fn, run
 
@@ -1760,8 +1833,9 @@ def _build_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
                     ns, out = stepf(s, tick, wl, fault)
                     if stop is None:
                         return ns, out
-                    live = (tick < budget) & ~stop
-                    return _freeze(live, ns, s), out
+                    with jax.named_scope("driver.freeze"):
+                        live = (tick < budget) & ~stop
+                        return _freeze(live, ns, s), out
 
                 return jax.lax.scan(tick_body, s0, xs)
 
@@ -1769,7 +1843,8 @@ def _build_fns(g: QueueGraph, profile: TransportProfile, p: SimParams,
             s, outs = jax.lax.cond(do_fast,
                                    lambda s0: chunk_scan(s0, None),
                                    lambda s0: chunk_scan(s0, stopped), s0)
-            return s, stopped | quiet(s, wl), outs
+            with jax.named_scope("driver.quiescent"):
+                return s, stopped | quiet(s, wl), outs
 
         return init_fn, run_chunk
 
@@ -1939,6 +2014,7 @@ def _stats_result(final: SimState, st: dict, msg_size, horizon: int,
         qlen_peak=int(st["qlen_peak"]),
         stat_abandon_tick=int(st["abandon_tick"]),
         telemetry=trace_obj,
+        driver_chunks=(int(st["fast_chunks"]), int(st["masked_chunks"])),
     )
 
 
@@ -2082,27 +2158,37 @@ def _run_batch(g, wls, profile, p, fault, seeds, trace, budget,
         return shard.run_sharded(g, wls, profile, p, fault, seeds, trace,
                                  budget, goodput_window, devices, tel=tel,
                                  link=link)
-    B, F = wls.src.shape
-    profile.delivery_modes(F)
-    init, run = driver_fns(g, profile, p, F, fault, trace, batched=True,
-                           tel=tel, link=link)
-    s0 = init(wls, seeds)
-    sizes = np.asarray(wls.size)
-    if trace == "stats":
-        w0, w1 = _window_bounds(goodput_window, budget)
-        final, st, horizon = run(s0, wls, fault, jnp.int32(budget),
-                                 jnp.int32(w0), jnp.int32(w1))
+    with _Spans(jax.profiler.TraceAnnotation) as span:
+        span("fabric.prepare")
+        B, F = wls.src.shape
+        profile.delivery_modes(F)
+        init, run = driver_fns(g, profile, p, F, fault, trace,
+                               batched=True, tel=tel, link=link)
+        sizes = np.asarray(wls.size)
+        span("fabric.init")
+        s0 = init(wls, seeds)
+        if trace == "stats":
+            w0, w1 = _window_bounds(goodput_window, budget)
+            span("fabric.run")
+            final, st, horizon = run(s0, wls, fault, jnp.int32(budget),
+                                     jnp.int32(w0), jnp.int32(w1))
+            span("fabric.fetch")
+            final = jax.device_get(final)
+            st = jax.device_get(st)
+            horizon = np.asarray(horizon)
+            span("fabric.split")
+            return _split_stats_results(final, st, sizes, horizon, budget,
+                                        goodput_window, B, tel=tel)
+        span("fabric.run")
+        final, outs, horizon = _run_full_host(run, s0, wls, fault, budget,
+                                              p.chunk_ticks, batch=B)
+        span("fabric.fetch")
         final = jax.device_get(final)
-        st = jax.device_get(st)
-        horizon = np.asarray(horizon)
-        return _split_stats_results(final, st, sizes, horizon, budget,
-                                    goodput_window, B, tel=tel)
-    final, outs, horizon = _run_full_host(run, s0, wls, fault, budget,
-                                          p.chunk_ticks, batch=B)
-    final = jax.device_get(final)
-    return _split_full_results(final, outs, sizes, horizon, budget, B)
+        span("fabric.split")
+        return _split_full_results(final, outs, sizes, horizon, budget, B)
 
 
+@partial(jax.profiler.annotate_function, name="fabric.simulate_batch")
 def simulate_batch(g: QueueGraph, wls: Workload,
                    profile=None, p: "SimParams | None" = None, *,
                    failed=None, faults=None, seeds=None,
@@ -2165,8 +2251,19 @@ def simulate_batch(g: QueueGraph, wls: Workload,
     corresponding serial ``simulate`` call: the tick function is the same
     compiled code, vmapped over the scenario axis with the carry donated,
     and each lane freezes at the same chunk boundary the serial run
-    exits at.
+    exits at. (``driver_chunks`` alone differs: it counts the chunks of
+    the loop that ran the lane, which here runs until the batch's, or
+    the device's, slowest lane stops.)
+
+    Under ``jax.profiler`` the call is the host span
+    ``fabric.simulate_batch``, cut into ``fabric.prepare``, ``.init``,
+    ``.run``, ``.fetch`` and ``.split`` (DESIGN.md, "Spans and
+    counters").
     """
+    # closed once the batch is ready to dispatch (on an error, with the
+    # frame); the dispatch opens its own spans
+    prep = _Spans(jax.profiler.TraceAnnotation)
+    prep("fabric.prepare")
     if isinstance(wls, (list, tuple)):
         wls = Workload.stack(wls)
     if shard or devices is not None:
@@ -2233,6 +2330,7 @@ def simulate_batch(g: QueueGraph, wls: Workload,
                 raise ValueError(f"failed mask must be [B={B}, "
                                  f"Q={g.num_queues}], got {dead.shape}")
             fault = FaultSchedule.from_mask(jnp.asarray(dead, bool))
+    prep.close()
 
     if profiles is None and graphs is None:
         return _run_batch(g, wls, profile, p, fault, seeds, trace, budget,

@@ -114,38 +114,48 @@ def run_sharded(g, wls, profile, p, fault, seeds, trace: str, budget: int,
     from repro.network.faults import FaultSchedule
     from repro.network.workloads import pad_scenarios
 
-    n = len(devs)
-    B, F = wls.src.shape
-    profile.delivery_modes(F)
-    init, run = fabric.driver_fns(g, profile, p, F, fault, trace,
-                                  batched=True, tel=tel, link=link,
-                                  devs=devs)
-    wls_p, pad = pad_scenarios(wls, n)
-    if pad:
-        # padding lanes get all-healthy schedules at the batch's own
-        # host-lane width (zero-width when no endpoint faults ride)
-        fault = jax.tree_util.tree_map(
-            lambda a, e: jnp.concatenate([a, e.astype(a.dtype)]),
-            fault, FaultSchedule.healthy(g.num_queues, batch=pad,
-                                         num_hosts=fault.num_hosts))
-        seeds = jnp.concatenate(
-            [seeds, jnp.full((pad,), fabric.DEFAULT_SEED, jnp.uint32)])
-    s0 = init(wls_p, seeds)
-    sizes = np.asarray(wls.size)
-    if trace == "stats":
-        w0, w1 = fabric._window_bounds(goodput_window, budget)
-        final, st, horizon = run(s0, wls_p, fault, jnp.int32(budget),
-                                 jnp.int32(w0), jnp.int32(w1))
+    with fabric._Spans(jax.profiler.TraceAnnotation) as span:
+        span("fabric.prepare")
+        n = len(devs)
+        B, F = wls.src.shape
+        profile.delivery_modes(F)
+        init, run = fabric.driver_fns(g, profile, p, F, fault, trace,
+                                      batched=True, tel=tel, link=link,
+                                      devs=devs)
+        wls_p, pad = pad_scenarios(wls, n)
+        if pad:
+            # padding lanes get all-healthy schedules at the batch's own
+            # host-lane width (zero-width when no endpoint faults ride)
+            fault = jax.tree_util.tree_map(
+                lambda a, e: jnp.concatenate([a, e.astype(a.dtype)]),
+                fault, FaultSchedule.healthy(g.num_queues, batch=pad,
+                                             num_hosts=fault.num_hosts))
+            seeds = jnp.concatenate(
+                [seeds, jnp.full((pad,), fabric.DEFAULT_SEED, jnp.uint32)])
+        sizes = np.asarray(wls.size)
+        span("fabric.init")
+        s0 = init(wls_p, seeds)
+        if trace == "stats":
+            w0, w1 = fabric._window_bounds(goodput_window, budget)
+            span("fabric.run")
+            final, st, horizon = run(s0, wls_p, fault, jnp.int32(budget),
+                                     jnp.int32(w0), jnp.int32(w1))
+            span("fabric.fetch")
+            final = jax.device_get(final)
+            st = jax.device_get(st)
+            horizon = np.asarray(horizon)
+            span("fabric.split")
+            return fabric._split_stats_results(final, st, sizes, horizon,
+                                               budget, goodput_window, B,
+                                               tel=tel)
+        span("fabric.run")
+        final, outs, horizon = fabric._run_full_host(
+            run, s0, wls_p, fault, budget, p.chunk_ticks, batch=B + pad)
+        span("fabric.fetch")
         final = jax.device_get(final)
-        st = jax.device_get(st)
-        horizon = np.asarray(horizon)
-        return fabric._split_stats_results(final, st, sizes, horizon,
-                                           budget, goodput_window, B,
-                                           tel=tel)
-    final, outs, horizon = fabric._run_full_host(
-        run, s0, wls_p, fault, budget, p.chunk_ticks, batch=B + pad)
-    final = jax.device_get(final)
-    return fabric._split_full_results(final, outs, sizes, horizon, budget, B)
+        span("fabric.split")
+        return fabric._split_full_results(final, outs, sizes, horizon,
+                                          budget, B)
 
 
 def _smoke() -> int:  # pragma: no cover — CLI smoke for scripts/check.sh
