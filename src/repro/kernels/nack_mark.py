@@ -8,23 +8,35 @@ Several lanes may target one flow, and two lanes may carry the SAME
 tick), so the combine is OR, not add.
 
 TPU adaptation: a scatter is not available across lanes, so the mark is
-re-expressed as a contraction. For an F-row block,
+re-expressed as a contraction over the L NACK lanes,
 
-    hits[r, m] = sum_l rowhot[r, l] * (off[l] == m)
+    hits[r, m] = sum_l rowhot[r, l] * pos[m, l],
+    rowhot[r, l] = valid[l] & (flow[l] == r),  pos[m, l] = (off[l] == m),
 
-is an MXU-friendly [R, L] x [L, MP] matmul (counts are small integers,
-exact in f32), and `hits > 0` collapses duplicates back to the OR
-semantics. The bool plane then packs into uint32 ring words by two more
-matmuls, [R, MP] x [MP, W], against place-value matrices holding the
-low and the high 16 bits of each word: bits are distinct powers of two
-per word, so each pack-sum IS the OR, and every product and partial sum
-is an integer below 2^16, exact in f32. (Mosaic cannot split the lane
-axis into [W, 32] to pack on the VPU, and reduces no unsigned integers.)
+an MXU matmul [R, L] x [L, MP], and `hits > 0` collapses duplicates back
+to the OR semantics. The bool plane then packs into uint32 ring words by
+two more matmuls, [R, MP] x [MP, 128], against place-value matrices
+holding the low and the high 16 bits of each word: bits are distinct
+powers of two per word, so each pack-sum IS the OR. (Mosaic cannot split
+the lane axis into [W, 32] to pack on the VPU, and reduces no unsigned
+integers.)
 
-Block layout: (BLOCK_F rows) x (MP bit-lanes, a multiple of 128) per
-grid step; the lane operands (flow / off / valid) ride along whole, one
-value per padded row, column 0 — the same carrier layout the SACK
-kernels use for per-row scalars.
+Every matmul runs in one MXU pass on bfloat16 operands with float32
+accumulation, and is exact: the one-hots and the plane hold 0 or 1, the
+place values are powers of two up to 2^15, all of which bfloat16 holds
+exactly; every sum is an integer below 2^16 (a hit count is at most L,
+a pack-sum at most 2^16 - 1), which float32 holds exactly.
+
+Block layout: the grid is (row blocks, lane blocks), the lane blocks —
+the contraction axis — innermost and sequential. The lane operands ride
+lane-major, one int32 [8, LB] block per step (rows flow, off, valid), so
+each step builds its block's rowhot [RB, LB] and pos [MP, LB] once, adds
+their contraction into a float32 [RB, MP] VMEM accumulator, and the last
+lane block packs it and writes `rtx | words` to the [RB, 128] output
+block. The row block RB is all of F rounded up to 16 up to `MAX_ROWS`
+rows, so at the fabric's widths each scenario builds each one-hot
+element once; LB, a multiple of 128, is sized from RB and MP so that one
+one-hot temporary stays near `ONEHOT_BYTES`.
 """
 from __future__ import annotations
 
@@ -33,43 +45,73 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_F = 64
 WORD = 32
+MAX_ROWS = 1024           # rows of one block: bounds the accumulator
+ONEHOT_BYTES = 1 << 21    # an int32 one-hot temporary of one lane block
 
 
-def _nack_kernel(rtx_ref, flow_ref, off_ref, valid_ref, out_ref,
-                 *, w: int, lanes: int, num_flows: int):
-    rtx = rtx_ref[:, :w]                             # [R, W] uint32
-    flow = flow_ref[...][:, 0]                       # [Lp] int32
-    off = off_ref[...][:, 0]                         # [Lp] int32
-    valid = valid_ref[...][:, 0] != 0                # [Lp]
-    R = rtx.shape[0]
-    mp = w * WORD
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
 
-    lane_col = jax.lax.broadcasted_iota(jnp.int32, (R, flow.shape[0]), 1)
-    valid = valid & (lane_col[0] < lanes) & (flow >= 0) & (flow < num_flows)
 
-    # global row ids of this block
-    f0 = pl.program_id(0) * BLOCK_F
-    row = jax.lax.broadcasted_iota(jnp.int32, (R, flow.shape[0]), 0) + f0
-    rowhot = (flow[None, :] == row) & valid[None, :]          # [R, Lp]
+def _round_up(a: int, b: int) -> int:
+    return _cdiv(a, b) * b
 
-    m = jax.lax.broadcasted_iota(jnp.int32, (flow.shape[0], mp), 1)
-    posmat = (jnp.clip(off, 0, mp - 1)[:, None] == m)         # [Lp, MP]
-    hits = jnp.dot(rowhot.astype(jnp.float32), posmat.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)        # [R, MP]
-    plane = (hits > 0.5).astype(jnp.float32)
 
-    m = jax.lax.broadcasted_iota(jnp.int32, (mp, w), 0)
-    bit = m % WORD
-    in_word = (m // WORD) == jax.lax.broadcasted_iota(jnp.int32, (mp, w), 1)
-    place = (1 << (bit % 16)).astype(jnp.float32)
-    lo, hi = (jnp.dot(plane, jnp.where(in_word & half, place, 0.0),
-                      preferred_element_type=jnp.float32).astype(jnp.int32)
-              for half in (bit < 16, bit >= 16))                # [R, W]
-    words = jax.lax.bitcast_convert_type((hi << 16) | lo, jnp.uint32)
-    out_ref[:, :w] = rtx | words
+def _blocks(f: int, lanes: int, mpp: int) -> tuple[int, int, int, int]:
+    """(row block, row blocks, lane block, lane blocks) for F rows, L
+    lanes and MP bit-lanes: blocks as even as the alignment allows."""
+    nr = _cdiv(f, MAX_ROWS)
+    rb = _round_up(_cdiv(f, nr), 16)
+    lb_max = max(ONEHOT_BYTES // (4 * max(rb, mpp)) // 128 * 128, 128)
+    nk = _cdiv(lanes, lb_max)
+    return rb, nr, _round_up(_cdiv(lanes, nk), 128), nk
+
+
+def _one(mask):
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _nack_kernel(rtx_ref, lane_ref, out_ref, acc_ref,
+                 *, lanes: int, num_flows: int, mp: int):
+    i, k = pl.program_id(0), pl.program_id(1)
+    rb, mpp = acc_ref.shape
+    lb = lane_ref.shape[1]
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    flow = lane_ref[0:1, :]                                   # [1, LB]
+    off = jnp.clip(lane_ref[1:2, :], 0, mp - 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lb), 1) + k * lb
+    ok = ((lane_ref[2:3, :] != 0) & (lane < lanes)
+          & (flow >= 0) & (flow < num_flows))
+    row = jax.lax.broadcasted_iota(jnp.int32, (rb, lb), 0) + i * rb
+    rowhot = _one((row == flow) & ok)                         # [RB, LB]
+    pos = _one(jax.lax.broadcasted_iota(jnp.int32, (mpp, lb), 0) == off)
+    acc_ref[...] += jax.lax.dot_general(                      # [RB, MP]
+        rowhot, pos, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        plane = _one(acc_ref[...] > 0.5)
+        m = jax.lax.broadcasted_iota(jnp.int32, (mpp, 128), 0)
+        bit = m % WORD
+        in_word = (m // WORD) == jax.lax.broadcasted_iota(
+            jnp.int32, (mpp, 128), 1)
+        place = (1 << (bit % 16)).astype(jnp.float32)
+        lo, hi = (jnp.dot(plane,
+                          jnp.where(in_word & half, place,
+                                    0.0).astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32
+                          ).astype(jnp.int32)
+                  for half in (bit < 16, bit >= 16))          # [RB, 128]
+        words = jax.lax.bitcast_convert_type((hi << 16) | lo, jnp.uint32)
+        out_ref[...] = rtx_ref[...] | words
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -85,23 +127,23 @@ def nack_mark(rtx: jax.Array, flow: jax.Array, off: jax.Array,
     f, w = rtx.shape
     lanes = flow.shape[0]
     assert w <= 32
-    rows = -(-f // BLOCK_F) * BLOCK_F
-    lrows = -(-lanes // 8) * 8
-    rtx_p = jnp.pad(rtx, ((0, rows - f), (0, 128 - w)))
-    lane_pad = ((0, lrows - lanes), (0, 127))
-    flow_p = jnp.pad(flow.reshape(-1, 1), lane_pad)
-    off_p = jnp.pad(off.reshape(-1, 1), lane_pad)
-    valid_p = jnp.pad(valid.astype(jnp.int32).reshape(-1, 1), lane_pad)
+    mp = w * WORD
+    mpp = _round_up(mp, 128)
+    rb, nr, lb, nk = _blocks(f, lanes, mpp)
+    rtx_p = jnp.pad(rtx, ((0, rb * nr - f), (0, 128 - w)))
+    lane_p = jnp.pad(jnp.stack([flow, off, valid.astype(jnp.int32)]),
+                     ((0, 5), (0, lb * nk - lanes)))
 
-    grid = (rows // BLOCK_F,)
-    spec128 = pl.BlockSpec((BLOCK_F, 128), lambda i: (i, 0))
-    lane_spec = pl.BlockSpec((lrows, 128), lambda i: (0, 0))
+    row_spec = pl.BlockSpec((rb, 128), lambda i, k: (i, 0))
     out = pl.pallas_call(
-        functools.partial(_nack_kernel, w=w, lanes=lanes, num_flows=f),
-        grid=grid,
-        in_specs=[spec128, lane_spec, lane_spec, lane_spec],
-        out_specs=spec128,
-        out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
+        functools.partial(_nack_kernel, lanes=lanes, num_flows=f, mp=mp),
+        grid=(nr, nk),
+        in_specs=[row_spec, pl.BlockSpec((8, lb), lambda i, k: (0, k))],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((rb * nr, 128), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((rb, mpp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(rtx_p, flow_p, off_p, valid_p)
+    )(rtx_p, lane_p)
     return out[:f, :w]

@@ -96,12 +96,27 @@ def test_ecmp_determinism_and_spread():
     np.testing.assert_allclose(hist, 0.25, atol=0.02)
 
 
-@pytest.mark.parametrize("f,w,lanes", [(1, 2, 5), (9, 16, 64), (130, 4, 300)])
-def test_nack_mark_matches_ref(f, w, lanes):
+@pytest.mark.parametrize("f,w,lanes,same", [
+    pytest.param(1, 2, 5, False, id="1-2-5"),
+    pytest.param(9, 16, 64, False, id="9-16-64"),
+    pytest.param(130, 4, 300, False, id="130-4-300"),
+    # the benchmark ring's widths: 480 flows, 16 ring words, Q + 2F lanes
+    pytest.param(480, 16, 1280, False, id="480-16-1280"),
+    # partial row and lane blocks; two row blocks
+    pytest.param(993, 16, 2306, False, id="993-16-2306"),
+    pytest.param(1100, 8, 300, False, id="1100-8-300"),
+    # every lane sets one bit: a hit count of L still thresholds to 1
+    pytest.param(480, 16, 1280, True, id="480-16-1280-same"),
+])
+def test_nack_mark_matches_ref(f, w, lanes, same):
     rtx = jnp.asarray(RNG.integers(0, 2 ** 32, (f, w), dtype=np.uint32))
     flow = jnp.asarray(RNG.integers(-2, f + 2, lanes), jnp.int32)
     off = jnp.asarray(RNG.integers(-4, w * 32 + 8, lanes), jnp.int32)
     valid = jnp.asarray(RNG.integers(0, 2, lanes).astype(bool))
+    if same:
+        flow, off = (jnp.full((lanes,), v, jnp.int32)
+                     for v in RNG.integers(0, [f, w * 32]))
+        valid = jnp.ones((lanes,), bool)
     # the fabric always hands the kernel in-range rows/offsets; clip the
     # sweep the same way so both paths see the contract inputs
     valid = valid & (flow >= 0) & (flow < f) & (off >= 0) & (off < w * 32)

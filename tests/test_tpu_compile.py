@@ -6,8 +6,9 @@ scatters, lane-splitting reshapes, too much fast memory). So the three
 hot-path kernels, and the batched stats driver with them switched on,
 are compiled at the widths `chip_smoke.py` runs on the chip: the
 64-rank tree all-reduce on ``paper_fig2()`` (126 flows, 16 ring words,
-572 NACK lanes), vmapped over its 128 scenarios. Nothing runs, so these
-say nothing of results or times.
+572 NACK lanes), vmapped over its 128 scenarios; and `nack_mark` at the
+ring all-reduce's widths (16 and 32 ranks), vmapped over 8. Nothing
+runs, so these say nothing of results or times.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
@@ -117,6 +118,21 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, smoke_widths):
         f = jax.vmap(fn) if batch else fn
         text = jax.jit(f).lower(*args).compile().as_text()
         assert TPU_CUSTOM_CALL in text, (kernel, batch)
+
+
+@pytest.mark.parametrize("F,W,L", [
+    (480, 16, 1280),       # the benchmark's 16-rank ring all-reduce
+    (1984, 16, 4288),      # the same ring over 32 ranks
+])
+def test_nack_mark_compiles_for_v5e_at_ring_widths(F, W, L, one_chip):
+    """`nack_mark` vmapped over a batch of 8 at the ring all-reduce's
+    widths fits the chip's default 16 MB of scoped VMEM."""
+    B = 8
+    args = [_shape(one_chip, (B,) + s, d) for s, d in
+            [((F, W), jnp.uint32), ((L,), jnp.int32), ((L,), jnp.int32),
+             ((L,), jnp.bool_)]]
+    text = jax.jit(jax.vmap(nack_mark)).lower(*args).compile().as_text()
+    assert TPU_CUSTOM_CALL in text
 
 
 def test_batched_stats_driver_compiles_with_kernels(one_chip, smoke_batch,
