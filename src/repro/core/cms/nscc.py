@@ -37,6 +37,51 @@ import jax
 import jax.numpy as jnp
 
 
+def div(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a / b`` in float32, rounded to nearest even as IEEE 754 says, on
+    every backend. A TPU's float32 divide is not correctly rounded (an
+    ulp off on about a third of inputs), so a window updated with ``/``
+    there drifts from the same scenario's window on a CPU. The backend's
+    divide only estimates the quotient of the significands; the exact
+    remainder (integer products, wrapped to 32 bits: the remainder is
+    small) corrects it and decides the rounding. No integer divide: a
+    TPU emulates those at length. Domain: `a` zero or normal, `b`
+    positive and normal, the quotient normal (every window quotient
+    below is).
+    """
+    a = jnp.asarray(a, jnp.float32)
+    b = jnp.asarray(b, jnp.float32)
+    u32, i32, f32 = jnp.uint32, jnp.int32, jnp.float32
+    ab = jax.lax.bitcast_convert_type(a, u32)
+    bb = jax.lax.bitcast_convert_type(b, u32)
+    ea = ((ab >> 23) & 0xFF).astype(i32)
+    eb = ((bb >> 23) & 0xFF).astype(i32)
+    ma = (ab & 0x7FFFFF) | 0x800000
+    mb = (bb & 0x7FFFFF) | 0x800000
+    # scale ma into [mb, 2 mb): the quotient's leading bit is then 1
+    low = ma < mb
+    ma = jnp.where(low, ma << 1, ma)
+    e = ea - eb + 127 - low.astype(i32)
+    # c ~ floor(ma 2^23 / mb) within a few units; r = ma 2^23 - c mb is
+    # exact mod 2^32 and |r| < 2^31, so exact
+    c = jnp.floor(ma.astype(f32) / mb.astype(f32) * 2.0 ** 23).astype(u32)
+    r = ((ma << 23) - c * mb).astype(i32)
+    k = jnp.floor(r.astype(f32) / mb.astype(f32)).astype(i32)
+    c, r = c + k.astype(u32), r - k * mb.astype(i32)
+    for _ in range(2):                     # k may be one off either way
+        neg = r < 0
+        c, r = jnp.where(neg, c - 1, c), jnp.where(neg, r + mb.astype(i32), r)
+        big = r >= mb.astype(i32)
+        c, r = jnp.where(big, c + 1, c), jnp.where(big, r - mb.astype(i32), r)
+    r2 = r << 1
+    mbi = mb.astype(i32)
+    q = c + ((r2 > mbi) | ((r2 == mbi) & ((c & 1) == 1))).astype(u32)
+    carry = q >> 24                        # rounded up to 2^24
+    q, e = q >> carry, e + carry.astype(i32)
+    bits = (ab & u32(0x80000000)) | (e.astype(u32) << 23) | (q & 0x7FFFFF)
+    return jnp.where(a == 0, a, jax.lax.bitcast_convert_type(bits, f32))
+
+
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class NSCCParams:
@@ -95,13 +140,14 @@ def window_delta(cwnd: jax.Array, ecn: jax.Array, rtt: jax.Array,
     target = params.base_rtt * params.target_factor
     high = rtt > target
     # case 2: aggressive MD proportional to RTT excess, per incoming ACK
-    overload = jnp.clip((rtt - target) / jnp.maximum(rtt, 1e-6), 0.0, 1.0)
+    overload = jnp.clip(div(rtt - target, jnp.maximum(rtt, 1e-6)), 0.0,
+                        1.0)
     dec = -params.md * overload  # packets per ACK
     # case 3: quick increase guessing from measured vs expected RTT
-    gap = jnp.clip((target - rtt) / target, 0.0, 1.0)
+    gap = jnp.clip(div(target - rtt, target), 0.0, 1.0)
     quick = params.quick_gain * gap
     # case 4: gentle additive increase (+ai per full window of ACKs)
-    gentle = params.ai / jnp.maximum(cwnd, 1.0)
+    gentle = div(params.ai, jnp.maximum(cwnd, 1.0))
     return jnp.where(ecn, jnp.where(high, dec, 0.0),
                      jnp.where(high, gentle, quick))
 
@@ -164,7 +210,7 @@ def quick_adapt(state: NSCCState, params: NSCCParams,
     due = (now - state.epoch_tick) >= epoch_len
     delivered = state.epoch_acked.astype(jnp.float32)
     lost = state.epoch_lost.astype(jnp.float32)
-    frac = delivered / jnp.maximum(delivered + lost, 1.0)
+    frac = div(delivered, jnp.maximum(delivered + lost, 1.0))
     lossy = due & (state.epoch_lost > 0)
     new_cwnd = jnp.where(
         lossy,

@@ -1,6 +1,8 @@
 """The simulator's own tracing: named phase scopes in the compiled
 driver, the driver's chunk counter, and the host spans of
-``simulate_batch`` (DESIGN.md, "Spans and counters").
+``simulate_batch`` (DESIGN.md, "Spans and counters"); and that the
+outcome the benchmark checks is the same bits as before NSCC's IEEE
+division.
 
 Scopes are HLO ``op_name`` metadata; the golden and batch == serial
 tests hold every lane's bits. conftest.py splits the CPU into 4 virtual
@@ -8,6 +10,7 @@ devices for the sharded case.
 """
 import dataclasses
 import glob
+import hashlib
 import re
 
 import jax
@@ -15,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.network import fabric
+from repro.network.collectives import CollectiveSpec, build_workload
 from repro.network.fabric import (SimParams, Workload, simulate,
                                   simulate_batch)
 from repro.network.faults import FaultSchedule
@@ -155,3 +159,44 @@ def test_simulate_batch_host_spans(g, tmp_path):
     inner = spans[1:]
     assert all(outer[1] <= s and t <= outer[2] for _, s, t in inner)
     assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+
+
+def _collective(kind, algo, ranks, size):
+    return build_workload(CollectiveSpec(kind, tuple(range(ranks)), size),
+                          algo)
+
+
+#: the state fields and stat lanes that the benchmark's check compares
+#: with its plain reference (``bench/check.py``'s ``program_outcome``)
+OUTCOME_STATE = ("delivered", "next_psn", "inflight", "last_progress",
+                 "src_track", "rtx", "dst_track", "cc", "trims", "drops",
+                 "dups", "retransmits", "timeouts", "ticks_degraded", "q_len")
+OUTCOME_STATS = ("horizon", "stat_completion", "stat_src_completion",
+                 "stat_win_delivered", "qlen_peak")
+#: their sha256 over the three scenarios below, as the simulator gave it
+#: with NSCC's plain ``/`` (equal on a CPU, whose divide is IEEE)
+OUTCOME_DIGEST = \
+    "1c7f9ea1e81852d8afa38cb447c25f8f95489a7f4a36f5062d42b7de11613036"
+
+
+def test_ieee_division_leaves_the_compared_outcome_bitwise_unchanged(g):
+    """A lossless and a gray-link all-to-all lane, and an incast that
+    trims: the compared fields hash as they did with ``/``."""
+    p = SimParams(ticks=8192, chunk_ticks=CHUNK)
+    prof = TransportProfile.ai_full()
+    a2a = _collective("all_to_all", "round_robin", 8, 8 * 24)
+    fault = FaultSchedule.healthy(g.num_queues, batch=2)
+    loss = np.zeros((2, g.num_queues), np.float32)
+    loss[1, 1] = 0.05
+    rs = simulate_batch(g, Workload.stack([a2a] * 2), prof, p,
+                        faults=dataclasses.replace(fault, loss_p=loss),
+                        seeds=np.asarray([3, 4], np.uint32))
+    rs.append(simulate(g, Workload.of([0, 2, 4], [6, 6, 6], 300), prof, p))
+    assert rs[1].drops > 0 and rs[1].timeouts > 0 and rs[2].trims > 0
+    h = hashlib.sha256()
+    for r in rs:
+        for leaf in jax.tree_util.tree_leaves(
+                [getattr(r.state, f) for f in OUTCOME_STATE]
+                + [getattr(r, f) for f in OUTCOME_STATS]):
+            h.update(np.ascontiguousarray(leaf).tobytes())
+    assert h.hexdigest() == OUTCOME_DIGEST

@@ -295,3 +295,25 @@ def test_dfc_nscc_window_penalty():
                               jnp.ones(2, bool))
     np.testing.assert_allclose(np.asarray(st2.cwnd),
                                [64.0, 32.0, 48.0, 64.0], atol=1e-4)
+
+
+def test_nscc_division_is_ieee_rounded():
+    """NSCC's window quotients come from integer long division, so they
+    are the IEEE 754 float32 quotient, bit for bit, on any backend."""
+    from repro.core.cms.nscc import div
+    rng = np.random.default_rng(7)
+    n = 200_000
+    a = np.concatenate([
+        rng.uniform(-1e4, 1e4, n),
+        np.exp(rng.uniform(-20, 20, n)) * rng.choice([-1, 1], n),
+        np.arange(40000) - 12.5,                   # overload, gap numerators
+        np.ones(n),                                # gentle: ai / cwnd
+        rng.integers(0, 5000, n),                  # Quick Adapt's delivered
+        [0.0, -0.0, 1.0, 3.0]]).astype(np.float32)
+    b = np.concatenate([
+        rng.uniform(1e-6, 1e4, n), np.exp(rng.uniform(-13, 20, n)),
+        np.maximum(np.arange(40000), 1e-6), rng.uniform(1, 48, n),
+        rng.integers(1, 10000, n), [2.0, 2.0, 3.0, 1.0]]).astype(np.float32)
+    want = (a / b).astype(np.float32)
+    got = np.asarray(jax.jit(div)(a, b))
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
