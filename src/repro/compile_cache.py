@@ -24,6 +24,10 @@ def enable_persistent_cache() -> str:
     lives at ``<checkout>/.jax_cache``, a fixed path, so that the next
     process in the same checkout finds what this one compiled.
     """
+    # the simulator's spans live in op_name metadata, which JAX leaves
+    # out of the cache key by default: an executable compiled before a
+    # scope was added or renamed would come back with the old names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(CHECKOUT / ".jax_cache")

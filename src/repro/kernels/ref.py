@@ -89,3 +89,15 @@ def nack_mark_ref(rtx: jax.Array, flow: jax.Array, off: jax.Array,
              << jnp.arange(32, dtype=jnp.uint32)).sum(axis=2,
                                                       dtype=jnp.uint32)
     return rtx | words
+
+
+def queue_write_ref(q_pkt: jax.Array, tq: jax.Array, wslot: jax.Array,
+                    cand_pkt: jax.Array) -> jax.Array:
+    """The enqueue write (fabric tick, ``tick.enqueue.write``): candidate
+    i's packet record lands in slot wslot[i] of queue tq[i]; a candidate
+    with tq[i] == Q (out of range) is dropped.
+
+    q_pkt: [Q, C, K] int32; tq, wslot: [n] int32 (wslot in [0, C));
+    cand_pkt: [n, K] int32. Returns q_pkt with the records written.
+    """
+    return q_pkt.at[tq, wslot].set(cand_pkt, mode="drop")

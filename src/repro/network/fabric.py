@@ -1066,7 +1066,8 @@ def make_step(g: QueueGraph, profile: TransportProfile, p: SimParams, F: int,
         tq = jnp.where(fits, cand_q, Q)
         cand_pkt = jnp.stack(
             [cand_flow, cand_psn, cand_ev, cand_meta, cand_ts], axis=-1)
-        q_pkt = s.q_pkt.at[tq, wslot].set(cand_pkt, mode="drop")
+        with jax.named_scope("tick.enqueue.write"):
+            q_pkt = kops.queue_write(s.q_pkt, tq, wslot, cand_pkt)
         hot_enq = (cand_q[None, :] == qidx[:, None]) & fits[None, :]  # [Q, n]
         added = hot_enq.sum(axis=1, dtype=jnp.int32)
         q_len = q_len + added

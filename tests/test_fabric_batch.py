@@ -5,7 +5,9 @@
   ring, base wrap-around), and interpret mode agrees with the compiled
   kernel (compiled only on TPU);
 * `simulate_batch` lanes are bitwise identical to serial `simulate`
-  calls across mixed workloads, seeds, and failure masks.
+  calls across mixed workloads, seeds, and failure masks;
+* the enqueue write's dense TPU path gives the scatter's runs bit for
+  bit.
 """
 import functools
 
@@ -16,11 +18,16 @@ import pytest
 
 from repro.core import pds
 from repro.core.lb.schemes import LBScheme
-from repro.kernels import ref
+from repro.core.link import LinkConfig
+from repro.kernels import ops, ref
+from repro.kernels.queue_write import queue_write
 from repro.kernels.sack_fused import sack_fused
+from repro.network import fabric
+from repro.network.collectives import CollectiveSpec, build_workload
 from repro.network.fabric import SimParams, Workload, simulate, simulate_batch
+from repro.network.faults import FaultSchedule
 from repro.network.profile import TransportProfile
-from repro.network.topology import leaf_spine
+from repro.network.topology import fat_tree3, leaf_spine
 
 RNG = np.random.default_rng(11)
 
@@ -324,3 +331,57 @@ def test_run_cache_distinguishes_same_named_graphs():
     assert int(r1.state.delivered.sum()) == int(r2.state.delivered.sum())
     from repro.network.fabric import _cache_key
     assert _cache_key(g1, prof, p, 2, False) != _cache_key(g2, prof, p, 2, False)
+
+
+def _incast(g):
+    """Six senders on two leaves into one host: queues overflow."""
+    return Workload.stack([Workload.of([0, 1, 2, 4, 5, 6], [3] * 6,
+                                       60 + 20 * i) for i in range(2)])
+
+
+def _enqueue_cases():
+    """(graph, workloads, params, simulate_batch kwargs) of the three
+    runs the dense queue write must reproduce."""
+    p = SimParams(ticks=3000, queue_capacity=8, timeout_ticks=64)
+    g = leaf_spine(leaves=2, spines=2, hosts_per_leaf=4)
+    ups = [int(g.up1_table[1, i]) for i in range(2)]
+    gray = FaultSchedule.healthy(g.num_queues).lossy(ups, 0.05)
+    a2a = fat_tree3(k=4, pods=2)
+    spec = CollectiveSpec("all_to_all", tuple(range(8)), 16)
+    return {
+        "trims_gray": (g, _incast(g), p, {"faults": gray}),
+        "cbfc": (g, _incast(g), p,
+                 {"link": LinkConfig.on(llr=False, cbfc=True)}),
+        "a2a": (a2a, Workload.stack([build_workload(spec)] * 2),
+                SimParams(ticks=3000, queue_capacity=16), {}),
+    }
+
+
+@pytest.mark.parametrize("case", ["trims_gray", "cbfc", "a2a"])
+def test_dense_queue_write_matches_the_scatter(case, monkeypatch):
+    """The enqueue write steered to its dense TPU path (one-hot
+    contraction) gives the scatter's runs bit for bit: results, counters
+    and the final state, q_pkt included. The cases overflow and trim
+    under gray-link loss, back-pressure under CBFC, and put two ranks of
+    an all-to-all on each leaf."""
+    g, wls, p, kw = _enqueue_cases()[case]
+    prof = TransportProfile.ai_full()
+    want = simulate_batch(g, wls, prof, p, **kw)
+    dense = []
+    monkeypatch.setattr(fabric, "_RUN_CACHE", {})
+    monkeypatch.setattr(ops, "_dense_queue_write", lambda: True)
+    monkeypatch.setattr(ops, "_queue_write_dense",
+                        lambda *a: dense.append(1) or queue_write(*a))
+    got = simulate_batch(g, wls, prof, p, **kw)
+    assert dense, "the dense write was not traced"
+    busy = {"trims_gray": lambda r: r.trims > 0 and r.drops > 0,
+            "cbfc": lambda r: r.credit_stall_ticks > 0,
+            "a2a": lambda r: r.completion_tick() > 0}[case]
+    assert all(busy(r) for r in want)
+    for a, b in zip(want, got):
+        assert a.horizon == b.horizon
+        np.testing.assert_array_equal(a.stat_src_completion,
+                                      b.stat_src_completion)
+        np.testing.assert_array_equal(np.asarray(a.state.q_pkt),
+                                      np.asarray(b.state.q_pkt))
+        assert _state_equal(a.state, b.state)

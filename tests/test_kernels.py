@@ -1,6 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (interpret mode on CPU; `chip_smoke.py` runs the compiled kernels of the
 fabric tick against the same oracles on the chip)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,6 +11,7 @@ from repro.kernels import ops, ref
 from repro.kernels.ecmp_hash import ecmp_select
 from repro.kernels.nack_mark import nack_mark
 from repro.kernels.nscc_update import nscc_update
+from repro.kernels.queue_write import queue_write
 from repro.kernels.sack_bitmap import sack_advance
 
 RNG = np.random.default_rng(7)
@@ -147,3 +149,70 @@ def test_nack_mark_preserves_existing_bits():
         rtx, jnp.asarray([0], jnp.int32), jnp.asarray([1], jnp.int32),
         jnp.asarray([True]), interpret=True))
     assert out[0, 0] == 0x80000003 and out[1, 0] == 0x80000001
+
+
+def _enqueue(rng, kind, q=12, n=40, c=8):
+    """One tick's enqueue, as the fabric's enqueue phase builds it: each
+    candidate's target queue (or none), its arrival rank there, and the
+    slot it writes where it fits; `kind` picks the hard case."""
+    cq = np.where(rng.random(n) < 0.3, -1, rng.integers(0, q, n))
+    q_len = rng.integers(0, c + 1, q)
+    q_head = rng.integers(0, c, q)
+    cand = rng.integers(-2 ** 31, 2 ** 31, (n, 5), dtype=np.int64)
+    if kind == "overflow":            # queue 0 empty to full, 1 nearly full
+        cq[:c + 3] = 0
+        cq[c + 3:c + 6] = 1
+        q_len[:2] = 0, c - 1
+    elif kind == "wrap":              # every ring's next slot wraps past C
+        q_head[:] = c - 1
+        q_len[:] = rng.integers(0, 3, q)
+    elif kind == "extremes":
+        cand = rng.choice([-1, 0, 2 ** 31 - 1, -(2 ** 31 - 1), -2 ** 31],
+                          (n, 5))
+    elif kind == "none":
+        cq[:] = -1
+    elif kind == "one_queue":         # every candidate into queue 3
+        cq[:] = 3
+        q_len[3] = 0
+    rank = np.array([(cq[:i] == cq[i]).sum() for i in range(n)])
+    safe = np.where(cq >= 0, cq, 0)
+    pos = q_len[safe] + rank
+    fits = (cq >= 0) & (pos < c)
+    wslot = (q_head[safe] + pos) % c
+    tq = np.where(fits, cq, q)
+    q_pkt = rng.integers(-2 ** 31, 2 ** 31, (q, c, 5), dtype=np.int64)
+    return q_pkt, tq, wslot, cand
+
+
+@pytest.mark.parametrize("kind", ["random", "overflow", "wrap", "extremes",
+                                  "none", "one_queue"])
+def test_queue_write_dense_matches_the_scatter(kind):
+    """The enqueue write's dense path (one-hot MXU contraction over byte
+    limbs) against the scatter oracle, bit for bit, vmapped over 4."""
+    rng = np.random.default_rng(sum(map(ord, kind)))
+    args = [jnp.asarray(np.stack(a), jnp.int32) for a in
+            zip(*(_enqueue(rng, kind) for _ in range(4)))]
+    want = jax.vmap(ref.queue_write_ref)(*args)
+    got = jax.jit(jax.vmap(queue_write))(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if kind == "none":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(args[0]))
+
+
+@pytest.mark.parametrize("dense,bound,scatters", [
+    (False, ops.DENSE_WRITE_MAX, True),
+    (True, ops.DENSE_WRITE_MAX, False),
+    (True, 12 * 40 * 8 - 1, True),
+])
+def test_queue_write_path_follows_backend_and_shape(dense, bound, scatters,
+                                                    monkeypatch):
+    """The scatter off the TPU, and on it past the shape bound; the
+    contraction on it under the bound."""
+    monkeypatch.setattr(ops, "_dense_queue_write", lambda: dense)
+    monkeypatch.setattr(ops, "DENSE_WRITE_MAX", bound)
+    args = [jnp.asarray(a, jnp.int32) for a in
+            _enqueue(np.random.default_rng(0), "random")]
+    # a fresh function each time: JAX caches a function's traces
+    text = str(jax.make_jaxpr(lambda *a: ops.queue_write(*a))(*args))
+    assert ("scatter" in text) == scatters
+    assert ("dot_general" in text) != scatters

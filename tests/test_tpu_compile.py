@@ -6,9 +6,11 @@ scatters, lane-splitting reshapes, too much fast memory). So the three
 hot-path kernels, and the batched stats driver with them switched on,
 are compiled at the widths `chip_smoke.py` runs on the chip: the
 64-rank tree all-reduce on ``paper_fig2()`` (126 flows, 16 ring words,
-572 NACK lanes), vmapped over its 128 scenarios; and `nack_mark` at the
-ring all-reduce's widths (16 and 32 ranks), vmapped over 8. Nothing
-runs, so these say nothing of results or times.
+572 NACK lanes), vmapped over its 128 scenarios; `nack_mark` at the
+ring all-reduce's widths (16 and 32 ranks), vmapped over 8; and the
+batched driver at the benchmark ring cells' widths, whose enqueue write
+must be the dense contraction. Nothing runs, so these say nothing of
+results or times.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
@@ -19,6 +21,7 @@ and checks that its ``main`` refuses a host without a TPU.
 """
 import importlib.util
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -32,7 +35,11 @@ from repro.kernels.nack_mark import nack_mark
 from repro.kernels.sack_bitmap import sack_advance
 from repro.kernels.sack_fused import sack_fused
 from repro.network import fabric
+from repro.network.collectives import CollectiveSpec, build_workload
+from repro.network.faults import FaultSchedule
 from repro.network.topology import leaf_spine, paper_fig2
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # bench
 
 TPU_CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 V5E_HBM_BYTES = 16 * 2 ** 30
@@ -135,14 +142,10 @@ def test_nack_mark_compiles_for_v5e_at_ring_widths(F, W, L, one_chip):
     assert TPU_CUSTOM_CALL in text
 
 
-def test_batched_stats_driver_compiles_with_kernels(one_chip, smoke_batch,
-                                                    monkeypatch):
-    """The chip smoke's executable: the batched stats driver over its 128
-    paper_fig2 all-reduce scenarios, gray-link draw compiled in, with the
-    ops steered to the compiled kernels as on a TPU backend."""
-    b = smoke_batch
-    monkeypatch.setattr(ops, "_compiled_kernels", lambda: True)
-    wls, fault = b.stacked()
+def _compile_driver(one_chip, b, wls, fault):
+    """The batched stats driver of `b` over `wls`, gray-link draw
+    compiled in, with the ops steered as on a TPU backend (the caller's
+    monkeypatch), compiled for one v5e; it must fit the chip's HBM."""
     init_fn, run = fabric._build_fns(
         b.g, b.profile, b.params, int(wls.src.shape[1]), batched=True,
         trace="stats", lossy=True)
@@ -151,11 +154,63 @@ def test_batched_stats_driver_compiles_with_kernels(one_chip, smoke_batch,
         lambda x: _shape(one_chip, x.shape, x.dtype),
         (s0, wls, fault, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
     compiled = jax.jit(run, donate_argnums=(0,)).lower(*args).compile()
-    # each kernel in both branches of the fast/masked chunk cond
-    assert compiled.as_text().count(TPU_CUSTOM_CALL) == 6
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < V5E_HBM_BYTES
+    return compiled.as_text()
+
+
+def test_batched_stats_driver_compiles_with_kernels(one_chip, smoke_batch,
+                                                    monkeypatch):
+    """The chip smoke's executable: the batched stats driver over its 128
+    paper_fig2 all-reduce scenarios, gray-link draw compiled in, with the
+    ops steered to the compiled kernels as on a TPU backend."""
+    monkeypatch.setattr(ops, "_compiled_kernels", lambda: True)
+    text = _compile_driver(one_chip, smoke_batch, *smoke_batch.stacked())
+    # each kernel in both branches of the fast/masked chunk cond
+    assert text.count(TPU_CUSTOM_CALL) == 6
+
+
+def test_ring_cell_driver_writes_its_queues_without_a_scatter(
+        one_chip, chip_smoke, monkeypatch):
+    """The benchmark ring cells' executable (NCCL's ring all-reduce over
+    16 ranks on paper_fig2: F=480, Q=320, C=64, a batch of 8), ops
+    steered as on a TPU backend: the enqueue write is the dense one-hot
+    contraction, no scatter under ``tick.enqueue.write``."""
+    cs = chip_smoke
+    b = cs.make_batch(paper_fig2(), 16, 64, 2, cs.BUDGET, cs.FLAP, cs.GRAY_P)
+    spec = CollectiveSpec("all_reduce", tuple(range(0, 64, 4)), 1024)
+    wls = fabric.Workload.stack([build_workload(spec, "ring")] * b.size)
+    assert (wls.src.shape, b.g.num_queues) == ((8, 480), 320)
+    monkeypatch.setattr(ops, "_compiled_kernels", lambda: True)
+    text = _compile_driver(one_chip, b, wls, FaultSchedule.stack(b.faults))
+    write = [ln for ln in text.splitlines() if "tick.enqueue.write" in ln]
+    assert any(" convolution(" in ln or " dot(" in ln for ln in write)
+    assert not [s for s in _scatter_scopes(text)
+                if (s or "").startswith("tick.enqueue.write")]
+
+
+def _scatter_scopes(text: str) -> list:
+    """The scope the benchmark's trace reader gives each scatter of a
+    compiled module: its own, or that of the fusion that calls it (a
+    TPU scatter sits in a fusion whose op_name may be the only one)."""
+    from bench import phase_reduce
+
+    scopes = phase_reduce.hlo_scopes(text)
+    comp, held, out = None, set(), []
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        elif re.match(r"\s*(?:ROOT\s+)?%[\w.\-]+\s*=.*\sscatter\(", line):
+            held.add(comp)
+            out.append(scopes.get(line.split("=")[0].split("%")[1].strip()))
+    for line in text.splitlines():
+        call = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=.*calls=%([\w.\-]+)",
+                        line)
+        if call and call.group(2) in held:
+            out.append(scopes.get(call.group(1)))
+    return out
 
 
 def test_chip_smoke_phases_tiny_on_cpu(chip_smoke):

@@ -32,7 +32,7 @@ SCOPE = re.compile(r"(?:^|[/(])((?:tick|driver)\.[a-z_]+(?:\.[a-z_]+)*)"
 #: the scopes every stats-tier batched driver compiles
 BASE = {"tick.faults", "tick.control", "tick.grants", "tick.injection",
         "tick.forwarding", "tick.delivery", "tick.enqueue",
-        "tick.control_tc", "tick.timeouts", "tick.recovery",
+        "tick.enqueue.write", "tick.control_tc", "tick.timeouts", "tick.recovery",
         "driver.freeze", "driver.quiescent", "driver.stats"}
 
 
